@@ -39,7 +39,6 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.config import ModelConfig, SALSConfig
@@ -384,8 +383,8 @@ def _grouped_shardmap(q0, q_lat, k_lat, k_scale, v_q, v_scale, v_zero, u,
         ks = k_scale_a if k_scale is not None else None
         return local_fn(q0, q_lat, k_lat, ks, v_q, v_scale, v_zero, u, pos)
 
-    return shard_map(wrapper, mesh=ctx.mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)(
+    return jax.shard_map(wrapper, mesh=ctx.mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(
         q0, q_lat, k_lat, k_scale_arg, v_q, v_scale, v_zero, u, pos_arr)
 
 

@@ -25,8 +25,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.sharding import axis_size
-
 MIN_COMPRESS = 4096
 
 
@@ -49,7 +47,7 @@ def compressed_mean_grads(grads, residual, axis_names: Tuple[str, ...]):
     """
     n = 1
     for ax in axis_names:
-        n *= axis_size(ax)
+        n *= jax.lax.axis_size(ax)
 
     def one(g, r):
         g = g.astype(jnp.float32)

@@ -26,8 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.sharding import axis_size
-
 
 def stage_slice(tree, stage: int, n_stages: int, n_layers: int):
     """Slice stacked (L, ...) block params to one stage's layers."""
@@ -50,7 +48,7 @@ def gpipe_loss(block_fn: Callable, embed_fn: Callable, head_loss_fn: Callable,
     """
 
     def loss_fn(stage_blocks, io_params, batch):
-        p = axis_size(axis)
+        p = jax.lax.axis_size(axis)
         sid = jax.lax.axis_index(axis)
         m = jax.tree.leaves(batch)[0].shape[0]
         t_total = m + p - 1

@@ -84,13 +84,15 @@ def logical_spec(logical) -> P:
     return ctx.spec(logical)
 
 
-def axis_size(ax) -> int:
-    """Static mesh-axis size inside shard_map, across jax versions:
-    jax >= 0.6 exposes lax.axis_size; 0.4.x returns the int from
-    core.axis_frame."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(ax)
-    return jax.core.axis_frame(ax)
+def make_mesh(shape: Tuple[int, ...], axis_names: Tuple[str, ...],
+              devices=None) -> Mesh:
+    """A mesh whose axes are all ``Auto``: shardings are constraints the
+    compiler propagates (``with_sharding_constraint`` and ``shard_map``
+    work on them), not the explicit per-array types ``jax.make_mesh``
+    now gives by default."""
+    return jax.make_mesh(shape, axis_names,
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axis_names), devices=devices)
 
 
 def mesh_axes_for(logical: str) -> Tuple[Tuple[str, ...], int]:

@@ -24,14 +24,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
 from repro.kernels.ref import NEG_INF
 
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *,
@@ -143,7 +140,8 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((bq, 1), jnp.float32),    # running sum l
             pltpu.VMEM((bq, dh), jnp.float32),   # output accumulator
         ],
-        interpret=_interpret(),
+        interpret=interpret_mode(),
+        name="flash_attention",
     )(qt, kt, vt)
     out = jnp.moveaxis(out, 1, 2)
     return out[:, :sq]

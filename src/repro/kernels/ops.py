@@ -1,9 +1,12 @@
 """Public kernel entry points with backend dispatch.
 
-backend="xla"    — pure-jnp implementations (chunked/online-softmax flash);
-                   what the multi-pod dry-run lowers, and the CPU default.
+The default backend follows the platform: ``pallas`` on TPU, ``xla`` on
+every other platform.  ``xla`` and ``naive`` exist as test oracles:
+
 backend="pallas" — pl.pallas_call TPU kernels (interpret=True on CPU so the
                    same code validates here and compiles on real TPUs).
+backend="xla"    — pure-jnp implementations (chunked/online-softmax flash);
+                   what the multi-pod dry-run lowers, and the CPU default.
 backend="naive"  — materialized reference (small shapes / tests).
 
 Models call these; nothing below imports from repro.models.
@@ -20,20 +23,28 @@ from repro.kernels import ref as _ref
 
 NEG_INF = _ref.NEG_INF
 
-_DEFAULT_BACKEND = "xla"
-_NAIVE_MAX_SEQ = 2048          # below this, materialized attention is fine
+_DEFAULT_BACKEND: Optional[str] = None     # None: follow the platform
+# Prefill attention up to this many query and key positions runs the
+# materialized reference on every backend: one fused XLA softmax whose
+# (Sq, Sk) logits are small at these lengths.  The flash kernels serve
+# longer monolithic prefills, where those logits would not fit.
+MATERIALIZED_MAX_SEQ = 2048
 _Q_BLOCK = 512
 _KV_BLOCK = 512
 
 
-def set_default_backend(name: str) -> None:
+def set_default_backend(name: Optional[str]) -> None:
+    """Override the process-wide backend (tests comparing against an
+    oracle); ``None`` restores the platform default."""
     global _DEFAULT_BACKEND
-    assert name in ("xla", "pallas", "naive")
+    assert name in (None, "xla", "pallas", "naive")
     _DEFAULT_BACKEND = name
 
 
 def default_backend() -> str:
-    return _DEFAULT_BACKEND
+    if _DEFAULT_BACKEND is not None:
+        return _DEFAULT_BACKEND
+    return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
 # ---------------------------------------------------------------------------
@@ -50,12 +61,14 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     ``prefix_len`` > 0 gives prefix-LM masking (first ``prefix_len`` kv
     positions bidirectional, the rest causal — paligemma) without ever
     materializing an (Sq, Sk) mask.  ``mask`` (broadcastable to
-    (B,H,Sq,Sk)) forces the naive path — tests only.
+    (B,H,Sq,Sk)) forces the naive path — tests only.  Shapes within
+    ``MATERIALIZED_MAX_SEQ`` take the materialized path on every backend
+    (see there); served prompts go through the chunked prefill instead.
     """
-    backend = backend or _DEFAULT_BACKEND
+    backend = backend or default_backend()
     sq, sk = q.shape[1], k.shape[1]
-    if mask is not None or (sq <= _NAIVE_MAX_SEQ and sk <= _NAIVE_MAX_SEQ) \
-            or backend == "naive":
+    if mask is not None or backend == "naive" or (
+            sq <= MATERIALIZED_MAX_SEQ and sk <= MATERIALIZED_MAX_SEQ):
         if mask is None and prefix_len:
             kvp = jnp.arange(sk)
             mask = ((kvp[None, :] < prefix_len) |
@@ -250,7 +263,7 @@ def latent_score(q_lat: jnp.ndarray, k_lat: jnp.ndarray,
     """q_lat (B, r*), k_lat (B, S, r) raw latents (+ optional int8 per-token
     ``k_scale`` (B, S)) -> (B, S) f32.  The Pallas path streams the leading
     r* columns via BlockSpec — no dense slice/pad/dequant copy."""
-    backend = backend or _DEFAULT_BACKEND
+    backend = backend or default_backend()
     if backend == "pallas":
         from repro.kernels import latent_score as ls
         return ls.latent_score_pallas(q_lat, k_lat, k_scale)
@@ -278,7 +291,7 @@ def latent_topk(q_lat: jnp.ndarray, k_lat: jnp.ndarray,
     through the table (scalar prefetch), the xla/naive path materializes
     the logical view (oracle-only dense copy).  Returned idx is LOGICAL
     and bit-identical to the dense layout."""
-    backend = backend or _DEFAULT_BACKEND
+    backend = backend or default_backend()
     if page_table is not None:
         if backend == "pallas":
             from repro.kernels import latent_score as ls
@@ -325,7 +338,7 @@ def sparse_recon_attention(q, k_lat, k_scale, v_q, v_scale, v_zero, u,
     Pallas path DMAs whole pages through the table (sorted idx → one DMA
     per page touched).  See ref.sparse_recon_attention_fused_ref for the
     full contract."""
-    backend = backend or _DEFAULT_BACKEND
+    backend = backend or default_backend()
     if page_table is not None:
         if backend == "pallas":
             from repro.kernels import sparse_recon_attention as sra
@@ -371,7 +384,7 @@ def sparse_recon_attention_window(q, k_lat, k_scale, v_q, v_scale, v_zero, u,
     tokens are gathered / dequantized / reconstructed ONCE.  Returns
     (m (B,Q,H), l (B,Q,H), o (B,Q,H,dh)); q_len = 1 is bit-identical to
     :func:`sparse_recon_attention`."""
-    backend = backend or _DEFAULT_BACKEND
+    backend = backend or default_backend()
     if page_table is not None:
         if backend == "pallas":
             from repro.kernels import sparse_recon_attention as sra

@@ -11,6 +11,10 @@ import jax
 import jax.numpy as jnp
 
 NEG_INF = -2.0 ** 30
+# The f32 contractions of the SALS oracles run at full f32 precision on
+# every platform (a TPU's default would round operands to bf16), so an
+# on-chip comparison with the Pallas kernels measures the kernels.
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def _rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
@@ -51,7 +55,8 @@ def latent_score_ref(q_lat: jnp.ndarray, k_lat: jnp.ndarray,
     ``k_scale`` (B, S): per-token dequant scale for int8 latents."""
     r_star = q_lat.shape[-1]
     scores = jnp.einsum("br,bsr->bs", q_lat.astype(jnp.float32),
-                        k_lat[..., :r_star].astype(jnp.float32))
+                        k_lat[..., :r_star].astype(jnp.float32),
+                        precision=_F32)
     if k_scale is not None:
         scores = scores * k_scale.astype(jnp.float32)
     return scores
@@ -232,17 +237,19 @@ def sparse_recon_attention_ref(q: jnp.ndarray, lat_sel: jnp.ndarray,
     n = lat_sel.shape[1]
     kvd = u.shape[0]
     group = h // (kvd // dh)
-    k_flat = lat_sel.astype(jnp.float32) @ u.T.astype(jnp.float32)  # (B,N,kvd)
+    k_flat = jnp.matmul(lat_sel.astype(jnp.float32), u.T.astype(jnp.float32),
+                        precision=_F32)  # (B,N,kvd)
     k_pre = k_flat.reshape(b, n, n_kv, dh)
     if use_rope:
         k_r = _rope(k_pre, jnp.broadcast_to(sel_pos, (b, n)), theta)
-        q_r = _rope(q[:, None], jnp.broadcast_to(
+        q_r = _rope(q[:, None].astype(jnp.float32), jnp.broadcast_to(
             jnp.asarray(q_pos).reshape(-1, 1), (b, 1)), theta)[:, 0]
     else:
         k_r, q_r = k_pre, q
     kk = jnp.repeat(k_r, group, axis=2)                             # (B,N,H,dh)
     logits = jnp.einsum("bhd,bnhd->bhn", q_r.astype(jnp.float32),
-                        kk.astype(jnp.float32)) * dh ** -0.5
+                        kk.astype(jnp.float32),
+                        precision=_F32) * dh ** -0.5
     if softcap:
         logits = softcap * jnp.tanh(logits / softcap)
     logits = jnp.where(valid[:, None, :], logits, NEG_INF)
@@ -250,7 +257,8 @@ def sparse_recon_attention_ref(q: jnp.ndarray, lat_sel: jnp.ndarray,
     p = jnp.where(logits <= NEG_INF / 2, 0.0, jnp.exp(logits - m[..., None]))
     l = jnp.sum(p, axis=-1)
     vv = jnp.repeat(v_sel.reshape(b, n, n_kv, dh), group, axis=2)
-    o = jnp.einsum("bhn,bnhd->bhd", p, vv.astype(jnp.float32))
+    o = jnp.einsum("bhn,bnhd->bhd", p, vv.astype(jnp.float32),
+                   precision=_F32)
     return m, l, o
 
 
@@ -319,16 +327,18 @@ def sparse_recon_attention_window_ref(q: jnp.ndarray, lat_sel: jnp.ndarray,
             theta=theta, softcap=softcap, use_rope=use_rope)
         return m[:, None], l[:, None], o[:, None]
     qpos = base[:, None] + jnp.arange(ql, dtype=jnp.int32)[None, :]  # (B,Q)
-    k_flat = lat_sel.astype(jnp.float32) @ u.T.astype(jnp.float32)  # (B,N,kvd)
+    k_flat = jnp.matmul(lat_sel.astype(jnp.float32), u.T.astype(jnp.float32),
+                        precision=_F32)  # (B,N,kvd)
     k_pre = k_flat.reshape(b, n, n_kv, dh)
     if use_rope:
         k_r = _rope(k_pre, jnp.broadcast_to(sel_pos, (b, n)), theta)
-        q_r = _rope(q, qpos, theta)
+        q_r = _rope(q.astype(jnp.float32), qpos, theta)
     else:
         k_r, q_r = k_pre, q
     kk = jnp.repeat(k_r, group, axis=2)                         # (B,N,H,dh)
     logits = jnp.einsum("bqhd,bnhd->bqhn", q_r.astype(jnp.float32),
-                        kk.astype(jnp.float32)) * dh ** -0.5
+                        kk.astype(jnp.float32),
+                        precision=_F32) * dh ** -0.5
     if softcap:
         logits = softcap * jnp.tanh(logits / softcap)
     ok = jnp.broadcast_to(valid[:, None, None, :], logits.shape)
@@ -340,5 +350,6 @@ def sparse_recon_attention_window_ref(q: jnp.ndarray, lat_sel: jnp.ndarray,
     p = jnp.where(logits <= NEG_INF / 2, 0.0, jnp.exp(logits - m[..., None]))
     l = jnp.sum(p, axis=-1)
     vv = jnp.repeat(v_sel.reshape(b, n, n_kv, dh), group, axis=2)
-    o = jnp.einsum("bqhn,bnhd->bqhd", p, vv.astype(jnp.float32))
+    o = jnp.einsum("bqhn,bnhd->bqhd", p, vv.astype(jnp.float32),
+                   precision=_F32)
     return m, l, o

@@ -4,49 +4,56 @@
 The top-k indices are the ONLY thing that travels from selection to this
 kernel.  The (B, N_c) index array arrives as a scalar-prefetch operand
 (``pltpu.PrefetchScalarGridSpec``); every cache operand's ``index_map``
-dereferences it, so the pipeline DMAs each selected token's row straight
-from the raw cache arrays in HBM into VMEM — the TPU analogue of the
-paper's fused Triton gather (and of paged attention with page size 1):
+dereferences it, so the pipeline DMAs the block holding each selected
+token straight from the raw cache arrays in HBM into VMEM — the TPU
+analogue of the paper's fused Triton gather:
 
-    k_lat   (B, S, r)       bf16 / f32 / int8 latents   -> (1, 1, r) block
-    k_scale (B, S)          int8 latent scale, optional -> (1, 1)
-    v_q     (B, S, code_w)  int8 / packed-int4 codes    -> (1, 1, code_w)
-    v_scale (B, S, G)       per-group quant scale       -> (1, 1, G)
-    v_zero  (B, S, G)       per-group quant zero        -> (1, 1, G)
+    dense  (B, S, ·) arrays    -> the aligned 8-row tile holding the token
+    paged  (n_pages, ps, ·)    -> the whole page holding the token
 
-Per selected token, entirely in registers/VMEM:
+(a TPU block's second-minor dim must be a multiple of 8, so a single row
+cannot be a block).  The selection is sorted ascending, so consecutive
+grid steps that land in the same tile or page keep the block index and
+Pallas skips the re-DMA.  The kernel picks the token's row from the block
+with a masked sublane sum.
 
-    1. dequantize the latent (int8 × scale) and the value codes,
+Per selected token, entirely in VMEM/vregs, in a flat (·, kvd) lane
+layout (head h owns lanes [h·dh, (h+1)·dh); no reshape crosses lanes
+into sublanes, which Mosaic refuses):
+
+    1. dequantize the latent (int8 × per-token scale, read from SMEM),
     2. reconstruct  k = k̃ · U_rᵀ  (one (1,r)·(r,kvd) matvec on the MXU),
-    3. RoPE at the token's *original* position (= its cache index, read
-       from the prefetched SMEM array),
-    4. GQA score vs the once-RoPE'd query (cached in VMEM scratch),
-    5. online-softmax accumulate (m, l, acc) across the N_c grid steps.
+    3. RoPE at the token's *original* position with lane rolls,
+    4. GQA logits: the RoPE'd query tile (q_len·group, kvd) times k, summed
+       per head's lane span -> (q_len·group, n_kv),
+    5. dequantize the value codes (int4 codes unpack to [even | odd]
+       channel order; the output is put back in order outside) and
+       online-softmax accumulate (m, l, acc) across the N_c grid steps.
 
-No gathered, dequantized, or reconstructed buffer ever touches HBM: the
-selected-token HBM traffic is exactly the §4.5 model's
-N_c·(r·b_lat + v_bytes), vs. the gather-then-attend path's additional
-read+write of dense (B, N_c, r) + (B, N_c, kvd) f32/bf16 intermediates.
-
+No gathered, dequantized, or reconstructed buffer ever touches HBM.
 Returns flash-style partials (m, l, o) for LSE-merging with the
 sink/recent-window partials (and across shards under a sequence-sharded
-cache).  Validated on CPU via ``interpret=True`` against
-``ref.sparse_recon_attention_fused_ref``.
+cache).
 
-WINDOWED variants (``sparse_recon_attention_window_pallas`` + paged twin,
-speculative decode): q carries a ``q_len <= 8`` draft-window axis; the
-selected set is gathered / dequantized / reconstructed / RoPE'd ONCE per
-grid step while all ``q_len`` queries (RoPE'd at ``q_pos + t``) score
-against it — the reconstruct-stream bytes are paid once per verify window
-instead of once per token.  A static ``n_recent`` applies the per-draft-
-position mask advance (query t only sees selected positions
+One kernel body serves all four entry points: dense or paged cache, and
+a query window of ``q_len`` positions (speculative verify; ``q_len = 1``
+is a single decode step).  With a window, each selected token is
+reconstructed ONCE and scored by every query (query t RoPE'd at
+``q_pos + t``), and a static ``n_recent`` applies the per-draft-position
+mask advance (query t only sees selected positions
 ``<= q_pos + t - n_recent``; younger positions belong to the ring /
-in-window region the caller merges).  With q_len = 1 the math reduces
-op-for-op to the single-token kernel — bit-identical outputs.
+in-window region the caller merges).  Since the single-token entry points
+run the same body with q_len = 1, the window of one is bit-identical to
+them by construction.
+
+Validated on CPU via ``interpret=True`` against
+``ref.sparse_recon_attention_fused_ref``; ``tests/test_tpu_compile.py``
+compiles it for v5e at Mistral widths.
 """
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -54,696 +61,268 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.ref import NEG_INF
+from repro.kernels import interpret_mode
+from repro.kernels.ref import NEG_INF, _rope
+
+_TILE = 8          # rows of the dense gather block / of a row pick
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-def _rope_one(x32: jnp.ndarray, pos, theta: float) -> jnp.ndarray:
-    """Half-rotation RoPE for one token. x32: (heads, dh) f32; pos scalar."""
-    dh = x32.shape[-1]
-    half = dh // 2
-    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
-    ang = pos.astype(jnp.float32) * freqs                   # (half,)
-    cos, sin = jnp.cos(ang)[None, :], jnp.sin(ang)[None, :]
-    x1, x2 = x32[:, :half], x32[:, half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-
-
-def _dequant_token(code: jnp.ndarray, scale: jnp.ndarray, zero: jnp.ndarray,
-                   v_bits: int, v_group: int) -> jnp.ndarray:
-    """In-register value dequant for one token.  code: (code_w,);
-    scale/zero: (G,).  Returns (kvd,) f32 (matches quantization.dequantize)."""
-    if v_bits == 4:
-        lo = (code & 0x0F).astype(jnp.float32)
-        hi = ((code >> 4) & 0x0F).astype(jnp.float32)
-        vals = jnp.stack([lo, hi], axis=-1).reshape(code.shape[0] * 2)
+def _pick_row(ref, row, dtype) -> jnp.ndarray:
+    """Row ``row`` of the (1, T, w) block ``ref`` as a (1, w) ``dtype``
+    value: an aligned 8-row slice, then a masked sublane sum (exact —
+    one term is the row, the rest are zeros)."""
+    t = ref.shape[1]
+    if t > _TILE:
+        start = pl.multiple_of((row // _TILE) * _TILE, _TILE)
+        x = ref[0, pl.ds(start, _TILE), :]
+        row = row - start
     else:
-        vals = code.astype(jnp.float32) + 128.0
-    vg = vals.reshape(-1, v_group)
-    out = vg * scale[:, None].astype(jnp.float32) \
-        + zero[:, None].astype(jnp.float32)
-    return out.reshape(vals.shape)
+        x = ref[0]
+    x = x.astype(dtype)
+    hit = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0) == row
+    return jnp.sum(jnp.where(hit, x, jnp.zeros_like(x)), axis=0,
+                   keepdims=True)
 
 
-def _fused_step(idx_ref, valid_ref, qpos_ref, base_ref, q_ref, lat_ref,
-                kscale_ref, vq_ref, vs_ref, vz_ref, u_ref, m_ref, l_ref,
-                o_ref, m_s, l_s, acc_s, q_s, *, n_kv: int, group: int,
-                theta: float, softcap: float, use_rope: bool, nc: int,
-                v_bits: int, v_group: int):
+def _spread(x: jnp.ndarray, key: jnp.ndarray, n: int) -> jnp.ndarray:
+    """(rows, n) -> (rows, kvd): lane i takes column ``key[i]`` of x."""
+    out = jnp.zeros((x.shape[0], key.shape[1]), jnp.float32)
+    for c in range(n):
+        out = jnp.where(key == c, x[:, c:c + 1], out)
+    return out
+
+
+def _head_sums(prod: jnp.ndarray, n_kv: int, dh: int) -> jnp.ndarray:
+    """(rows, kvd) -> (rows, n_kv): sum over each head's lane span."""
+    col = jax.lax.broadcasted_iota(jnp.int32, (prod.shape[0], n_kv), 1)
+    out = jnp.zeros((prod.shape[0], n_kv), jnp.float32)
+    for h in range(n_kv):
+        s = jnp.sum(prod[:, h * dh:(h + 1) * dh], axis=1, keepdims=True)
+        out = jnp.where(col == h, s, out)
+    return out
+
+
+def _rope_flat(k: jnp.ndarray, pos, freq: jnp.ndarray, dh: int
+               ) -> jnp.ndarray:
+    """Half-rotation RoPE of a (1, kvd) key at ``pos``; ``freq`` (1, kvd)
+    holds each lane's rotary frequency."""
+    half = dh // 2
+    kvd = k.shape[1]
+    ang = pos.astype(jnp.float32) * freq
+    lane = jax.lax.broadcasted_iota(jnp.int32, k.shape, 1) % dh
+    ahead = pltpu.roll(k, kvd - half, 1)          # k[i + half]
+    behind = pltpu.roll(k, half, 1)               # k[i - half]
+    rot = jnp.where(lane < half, -ahead, behind)
+    return k * jnp.cos(ang) + rot * jnp.sin(ang)
+
+
+def _value_lanes(kvd: int, v_bits: int) -> jnp.ndarray:
+    """(1, kvd) channel held by each value lane: natural order for int8,
+    [even channels | odd channels] for unpacked int4 codes."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, kvd), 1)
+    if v_bits != 4:
+        return lane
+    half = kvd // 2
+    return jnp.where(lane < half, 2 * lane, 2 * (lane - half) + 1)
+
+
+def _dequant_row(vq_ref, vs_ref, vz_ref, row, chan, *, v_bits: int,
+                 v_group: int) -> jnp.ndarray:
+    """One token's values (1, kvd) f32 in ``chan`` lane order (matches
+    quantization.dequantize)."""
+    if v_bits == 4:
+        code = _pick_row(vq_ref, row, jnp.int32)            # (1, kvd/2)
+        vals = jnp.concatenate([(code & 0x0F).astype(jnp.float32),
+                                ((code >> 4) & 0x0F).astype(jnp.float32)],
+                               axis=1)
+    else:
+        vals = _pick_row(vq_ref, row, jnp.float32) + 128.0
+    n_groups = vs_ref.shape[2]
+    grp = chan // v_group
+    scale = _spread(_pick_row(vs_ref, row, jnp.float32), grp, n_groups)
+    zero = _spread(_pick_row(vz_ref, row, jnp.float32), grp, n_groups)
+    return vals * scale + zero
+
+
+def _recon_kernel(idx_ref, valid_ref, qpos_ref, base_ref, ksel_ref, *rest,
+                  paged: bool, tile: int, n_kv: int, dh: int, group: int,
+                  softcap: float, use_rope: bool, nc: int, v_bits: int,
+                  v_group: int, n_recent: int):
+    if paged:
+        rest = rest[1:]                     # the page table feeds index maps
+    (q_ref, freq_ref, lat_ref, vq_ref, vs_ref, vz_ref, u_ref,
+     m_ref, l_ref, o_ref, m_s, l_s, acc_s) = rest
     b_, n_ = pl.program_id(0), pl.program_id(1)
-    h, dh = q_ref.shape[1], q_ref.shape[2]
+    kvd = n_kv * dh
 
     @pl.when(n_ == 0)
     def _init():
         m_s[...] = jnp.full_like(m_s, NEG_INF)
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
-        q32 = q_ref[0].astype(jnp.float32)                  # (H, dh)
-        q_s[...] = _rope_one(q32, qpos_ref[b_], theta) if use_rope else q32
 
-    # ---- 1. dequantize latent (this block IS cache row idx[b, n]) ---------
-    lat = lat_ref[0].astype(jnp.float32)                    # (1, r)
-    if kscale_ref is not None:
-        lat = lat * kscale_ref[0, 0].astype(jnp.float32)
+    tok = idx_ref[b_, n_]
+    row = jax.lax.rem(tok, tile)
 
-    # ---- 2. reconstruct: k = lat · Uᵀ  (1, r)·(kvd, r)ᵀ --------------------
-    k_flat = jax.lax.dot_general(
+    # ---- 1-2. dequantize the latent, reconstruct k = lat · Uᵀ --------------
+    lat = _pick_row(lat_ref, row, jnp.float32) * ksel_ref[b_, n_]   # (1, r)
+    k = jax.lax.dot_general(
         lat, u_ref[...].astype(jnp.float32), (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)                 # (1, kvd)
-    k_pre = k_flat.reshape(n_kv, dh)
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)                         # (1, kvd)
 
     # ---- 3. RoPE at the original position (= base + the cache index) ------
-    pos = idx_ref[b_, n_] + base_ref[b_]
-    k_r = _rope_one(k_pre, pos, theta) if use_rope else k_pre
+    pos = tok + base_ref[b_]
+    if use_rope:
+        k = _rope_flat(k, pos, freq_ref[...], dh)
 
-    # ---- 4. GQA score vs the cached RoPE'd query ---------------------------
-    q_g = q_s[...].reshape(n_kv, group, dh)
-    logits = jnp.sum(q_g * k_r[:, None, :], axis=-1)        # (n_kv, group)
-    logits = logits.reshape(h) * (dh ** -0.5)
+    # ---- 4. GQA logits of every (query, head) row vs this token ------------
+    logits = _head_sums(q_ref[0] * k, n_kv, dh) * (dh ** -0.5)
     if softcap:
         logits = softcap * jnp.tanh(logits / softcap)
-    logits = jnp.where(valid_ref[b_, n_] != 0, logits, NEG_INF)
-
-    # ---- 5. dequant value + online-softmax accumulate ----------------------
-    v_tok = _dequant_token(vq_ref[0, 0], vs_ref[0, 0], vz_ref[0, 0],
-                           v_bits, v_group).reshape(n_kv, dh)
-    m_prev = m_s[:, 0]
-    m_new = jnp.maximum(m_prev, logits)
-    p = jnp.where(logits <= NEG_INF / 2, 0.0, jnp.exp(logits - m_new))
-    alpha = jnp.exp(m_prev - m_new)
-    l_s[:, 0] = l_s[:, 0] * alpha + p
-    p_g = p.reshape(n_kv, group)
-    acc_s[...] = acc_s[...] * alpha[:, None] \
-        + (p_g[:, :, None] * v_tok[:, None, :]).reshape(h, dh)
-    m_s[:, 0] = m_new
-
-    @pl.when(n_ == nc - 1)
-    def _finish():
-        m_ref[0] = m_s[:, 0]
-        l_ref[0] = l_s[:, 0]
-        o_ref[0] = acc_s[...]
-
-
-def _fused_kernel_plain(idx_ref, valid_ref, qpos_ref, base_ref, q_ref,
-                        lat_ref, vq_ref, vs_ref, vz_ref, u_ref, m_ref, l_ref,
-                        o_ref, m_s, l_s, acc_s, q_s, **kw):
-    _fused_step(idx_ref, valid_ref, qpos_ref, base_ref, q_ref, lat_ref, None,
-                vq_ref, vs_ref, vz_ref, u_ref, m_ref, l_ref, o_ref,
-                m_s, l_s, acc_s, q_s, **kw)
-
-
-def _fused_kernel_scaled(idx_ref, valid_ref, qpos_ref, base_ref, q_ref,
-                         lat_ref, kscale_ref, vq_ref, vs_ref, vz_ref, u_ref,
-                         m_ref, l_ref, o_ref, m_s, l_s, acc_s, q_s, **kw):
-    _fused_step(idx_ref, valid_ref, qpos_ref, base_ref, q_ref, lat_ref,
-                kscale_ref, vq_ref, vs_ref, vz_ref, u_ref, m_ref, l_ref,
-                o_ref, m_s, l_s, acc_s, q_s, **kw)
-
-
-# ---------------------------------------------------------------------------
-# windowed variant (speculative decode): q_len queries share one selection
-# ---------------------------------------------------------------------------
-
-def _window_queries(q_ref, qpos_ref, q_s, b_, ql: int, h: int, theta: float,
-                    use_rope: bool):
-    """RoPE all ``ql`` window queries once into scratch (query t at
-    position qpos + t), stacked as (ql·h, dh)."""
-    for t in range(ql):
-        q32 = q_ref[0, t].astype(jnp.float32)               # (h, dh)
-        q_s[t * h:(t + 1) * h, :] = \
-            _rope_one(q32, qpos_ref[b_] + t, theta) if use_rope else q32
-
-
-def _window_accumulate(logits, valid_bit, pos, qpos, v_tok, m_s, l_s, acc_s,
-                       *, ql: int, h: int, dh: int, n_kv: int, group: int,
-                       softcap: float, n_recent: int):
-    """Shared online-softmax step over the (ql·h,) folded query axis.
-
-    ``n_recent`` > 0 gates query t to selected positions
-    ``pos <= qpos + t - n_recent`` (the per-draft-position mask advance);
-    0 disables the gate.  With ql = 1 every op matches the single-token
-    kernels bit-for-bit.
-    """
-    logits = logits.reshape(ql * h) * (dh ** -0.5)
-    if softcap:
-        logits = softcap * jnp.tanh(logits / softcap)
-    ok = valid_bit != 0
+    ok = valid_ref[b_, n_] != 0
     if n_recent:
-        t_of = jax.lax.broadcasted_iota(jnp.int32, (ql, h), 0) \
-            .reshape(ql * h)
-        ok = ok & (pos <= qpos + t_of - n_recent)
+        t_of = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 0) // group
+        ok = ok & (pos <= qpos_ref[b_] + t_of - n_recent)
     logits = jnp.where(ok, logits, NEG_INF)
-    m_prev = m_s[:, 0]
-    m_new = jnp.maximum(m_prev, logits)
-    p = jnp.where(logits <= NEG_INF / 2, 0.0, jnp.exp(logits - m_new))
-    alpha = jnp.exp(m_prev - m_new)
-    l_s[:, 0] = l_s[:, 0] * alpha + p
-    p_g = p.reshape(ql, n_kv, group)
-    acc_s[...] = acc_s[...] * alpha[:, None] \
-        + (p_g[..., None] * v_tok[None, :, None, :]).reshape(ql * h, dh)
-    m_s[:, 0] = m_new
-
-
-def _fused_window_step(idx_ref, valid_ref, qpos_ref, base_ref, q_ref, lat_ref,
-                       kscale_ref, vq_ref, vs_ref, vz_ref, u_ref, m_ref,
-                       l_ref, o_ref, m_s, l_s, acc_s, q_s, *, n_kv: int,
-                       group: int, theta: float, softcap: float,
-                       use_rope: bool, nc: int, v_bits: int, v_group: int,
-                       ql: int, n_recent: int):
-    """Windowed :func:`_fused_step`: the selected token is dequantized,
-    reconstructed, and RoPE'd ONCE, then scored by all ``ql`` cached
-    queries (folded into the head axis of the scratch accumulators)."""
-    b_, n_ = pl.program_id(0), pl.program_id(1)
-    h, dh = q_ref.shape[2], q_ref.shape[3]
-
-    @pl.when(n_ == 0)
-    def _init():
-        m_s[...] = jnp.full_like(m_s, NEG_INF)
-        l_s[...] = jnp.zeros_like(l_s)
-        acc_s[...] = jnp.zeros_like(acc_s)
-        _window_queries(q_ref, qpos_ref, q_s, b_, ql, h, theta, use_rope)
-
-    lat = lat_ref[0].astype(jnp.float32)                    # (1, r)
-    if kscale_ref is not None:
-        lat = lat * kscale_ref[0, 0].astype(jnp.float32)
-    k_flat = jax.lax.dot_general(
-        lat, u_ref[...].astype(jnp.float32), (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)                 # (1, kvd)
-    k_pre = k_flat.reshape(n_kv, dh)
-    pos = idx_ref[b_, n_] + base_ref[b_]
-    k_r = _rope_one(k_pre, pos, theta) if use_rope else k_pre
-
-    q_g = q_s[...].reshape(ql, n_kv, group, dh)
-    logits = jnp.sum(q_g * k_r[None, :, None, :], axis=-1)  # (ql,n_kv,group)
-    v_tok = _dequant_token(vq_ref[0, 0], vs_ref[0, 0], vz_ref[0, 0],
-                           v_bits, v_group).reshape(n_kv, dh)
-    _window_accumulate(logits, valid_ref[b_, n_], pos, qpos_ref[b_], v_tok,
-                       m_s, l_s, acc_s, ql=ql, h=h, dh=dh, n_kv=n_kv,
-                       group=group, softcap=softcap, n_recent=n_recent)
-
-    @pl.when(n_ == nc - 1)
-    def _finish():
-        m_ref[0] = m_s[:, 0].reshape(ql, h)
-        l_ref[0] = l_s[:, 0].reshape(ql, h)
-        o_ref[0] = acc_s[...].reshape(ql, h, dh)
-
-
-def _fused_window_plain(idx_ref, valid_ref, qpos_ref, base_ref, q_ref,
-                        lat_ref, vq_ref, vs_ref, vz_ref, u_ref, m_ref, l_ref,
-                        o_ref, m_s, l_s, acc_s, q_s, **kw):
-    _fused_window_step(idx_ref, valid_ref, qpos_ref, base_ref, q_ref, lat_ref,
-                       None, vq_ref, vs_ref, vz_ref, u_ref, m_ref, l_ref,
-                       o_ref, m_s, l_s, acc_s, q_s, **kw)
-
-
-def _fused_window_scaled(idx_ref, valid_ref, qpos_ref, base_ref, q_ref,
-                         lat_ref, kscale_ref, vq_ref, vs_ref, vz_ref, u_ref,
-                         m_ref, l_ref, o_ref, m_s, l_s, acc_s, q_s, **kw):
-    _fused_window_step(idx_ref, valid_ref, qpos_ref, base_ref, q_ref, lat_ref,
-                       kscale_ref, vq_ref, vs_ref, vz_ref, u_ref, m_ref,
-                       l_ref, o_ref, m_s, l_s, acc_s, q_s, **kw)
-
-
-@functools.partial(jax.jit, static_argnames=("n_kv", "n_recent", "v_bits",
-                                             "v_group", "theta", "softcap",
-                                             "use_rope"))
-def sparse_recon_attention_window_pallas(
-        q: jnp.ndarray, k_lat: jnp.ndarray, k_scale: Optional[jnp.ndarray],
-        v_q: jnp.ndarray, v_scale: jnp.ndarray, v_zero: jnp.ndarray,
-        u: jnp.ndarray, idx: jnp.ndarray, valid: jnp.ndarray, q_pos, *,
-        n_kv: int, n_recent: int = 0, v_bits: int = 8, v_group: int = 64,
-        theta: float = 10_000.0, softcap: float = 0.0, use_rope: bool = True,
-        pos_base: Optional[jnp.ndarray] = None
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Windowed fused decode attention (speculative verify window).
-
-    q: (B, q_len, H, dh) pre-RoPE queries; ``q_pos`` (scalar or (B,)) is
-    the WINDOW BASE — query t is RoPE'd at ``q_pos + t``.  One selected
-    set (idx/valid) serves the whole window: each token is reconstructed
-    once and attended by all queries, with the per-draft-position mask
-    advance applied in-kernel (``n_recent`` static; see module docstring).
-    Returns (m (B,Q,H), l (B,Q,H), o (B,Q,H,dh)) f32 partials.
-    """
-    b, ql, h, dh = q.shape
-    r = k_lat.shape[2]
-    code_w = v_q.shape[2]
-    g = v_scale.shape[2]
-    kvd = u.shape[0]
-    nc = idx.shape[1]
-    group = h // n_kv
-
-    idx_i = idx.astype(jnp.int32)
-    valid_i = valid.astype(jnp.int32)
-    qpos_b = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32).reshape(-1), (b,))
-    base_b = jnp.zeros((b,), jnp.int32) if pos_base is None \
-        else jnp.broadcast_to(jnp.asarray(pos_base, jnp.int32), (b,))
-
-    in_specs = [
-        pl.BlockSpec((1, ql, h, dh),
-                     lambda b_, n_, i_, v_, p_, bb_: (b_, 0, 0, 0)),
-        pl.BlockSpec((1, 1, r),
-                     lambda b_, n_, i_, v_, p_, bb_: (b_, i_[b_, n_], 0)),
-    ]
-    args = [q, k_lat]
-    kw = dict(n_kv=n_kv, group=group, theta=theta, softcap=softcap,
-              use_rope=use_rope, nc=nc, v_bits=v_bits, v_group=v_group,
-              ql=ql, n_recent=n_recent)
-    if k_scale is not None:
-        in_specs.append(
-            pl.BlockSpec((1, 1),
-                         lambda b_, n_, i_, v_, p_, bb_: (b_, i_[b_, n_])))
-        args.append(k_scale)
-        kernel = functools.partial(_fused_window_scaled, **kw)
-    else:
-        kernel = functools.partial(_fused_window_plain, **kw)
-    in_specs += [
-        pl.BlockSpec((1, 1, code_w),
-                     lambda b_, n_, i_, v_, p_, bb_: (b_, i_[b_, n_], 0)),
-        pl.BlockSpec((1, 1, g),
-                     lambda b_, n_, i_, v_, p_, bb_: (b_, i_[b_, n_], 0)),
-        pl.BlockSpec((1, 1, g),
-                     lambda b_, n_, i_, v_, p_, bb_: (b_, i_[b_, n_], 0)),
-        pl.BlockSpec((kvd, r), lambda b_, n_, i_, v_, p_, bb_: (0, 0)),
-    ]
-    args += [v_q, v_scale, v_zero, u]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(b, nc),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, ql, h),
-                         lambda b_, n_, i_, v_, p_, bb_: (b_, 0, 0)),
-            pl.BlockSpec((1, ql, h),
-                         lambda b_, n_, i_, v_, p_, bb_: (b_, 0, 0)),
-            pl.BlockSpec((1, ql, h, dh),
-                         lambda b_, n_, i_, v_, p_, bb_: (b_, 0, 0, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((ql * h, 1), jnp.float32),
-            pltpu.VMEM((ql * h, 1), jnp.float32),
-            pltpu.VMEM((ql * h, dh), jnp.float32),
-            pltpu.VMEM((ql * h, dh), jnp.float32),
-        ],
-    )
-    m, l, o = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, ql, h), jnp.float32),
-            jax.ShapeDtypeStruct((b, ql, h), jnp.float32),
-            jax.ShapeDtypeStruct((b, ql, h, dh), jnp.float32),
-        ],
-        interpret=_interpret(),
-    )(idx_i, valid_i, qpos_b, base_b, *args)
-    return m, l, o
-
-
-# ---------------------------------------------------------------------------
-# paged variant (ISSUE 5): whole-page DMA, page table as scalar prefetch
-# ---------------------------------------------------------------------------
-
-def _fused_paged_step(idx_ref, valid_ref, qpos_ref, base_ref, pt_ref, q_ref,
-                      lat_ref, kscale_ref, vq_ref, vs_ref, vz_ref, u_ref,
-                      m_ref, l_ref, o_ref, m_s, l_s, acc_s, q_s, *,
-                      n_kv: int, group: int, theta: float, softcap: float,
-                      use_rope: bool, nc: int, v_bits: int, v_group: int,
-                      ps: int):
-    """Identical math to :func:`_fused_step`, but each cache operand's block
-    is ONE WHOLE PAGE (``(1, ps, ·)``, physical page dereferenced from the
-    prefetched page table) and the kernel picks its token's in-page row.
-    With the selected indices sorted ascending (sparse_attention sorts the
-    top-k set before both layouts), consecutive grid steps that land on the
-    same page keep the same block index, so Pallas elides the re-DMA — the
-    page is fetched once per *page touched*, not once per token (the
-    ROADMAP page>1 open item)."""
-    b_, n_ = pl.program_id(0), pl.program_id(1)
-    h, dh = q_ref.shape[1], q_ref.shape[2]
-
-    @pl.when(n_ == 0)
-    def _init():
-        m_s[...] = jnp.full_like(m_s, NEG_INF)
-        l_s[...] = jnp.zeros_like(l_s)
-        acc_s[...] = jnp.zeros_like(acc_s)
-        q32 = q_ref[0].astype(jnp.float32)                  # (H, dh)
-        q_s[...] = _rope_one(q32, qpos_ref[b_], theta) if use_rope else q32
-
-    row = jax.lax.rem(idx_ref[b_, n_], ps)                  # in-page row
-    # ---- 1. dequantize latent (one row of the DMA'd page) -----------------
-    lat = jax.lax.dynamic_slice(lat_ref[0], (row, 0), (1, lat_ref.shape[2])) \
-        .astype(jnp.float32)                                # (1, r)
-    if kscale_ref is not None:
-        sc = jax.lax.dynamic_slice(kscale_ref[0], (row,), (1,))
-        lat = lat * sc.astype(jnp.float32)
-
-    # ---- 2. reconstruct: k = lat · Uᵀ --------------------------------------
-    k_flat = jax.lax.dot_general(
-        lat, u_ref[...].astype(jnp.float32), (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)                 # (1, kvd)
-    k_pre = k_flat.reshape(n_kv, dh)
-
-    # ---- 3. RoPE at the LOGICAL position (idx is logical) ------------------
-    pos = idx_ref[b_, n_] + base_ref[b_]
-    k_r = _rope_one(k_pre, pos, theta) if use_rope else k_pre
-
-    # ---- 4. GQA score vs the cached RoPE'd query ---------------------------
-    q_g = q_s[...].reshape(n_kv, group, dh)
-    logits = jnp.sum(q_g * k_r[:, None, :], axis=-1)
-    logits = logits.reshape(h) * (dh ** -0.5)
-    if softcap:
-        logits = softcap * jnp.tanh(logits / softcap)
-    logits = jnp.where(valid_ref[b_, n_] != 0, logits, NEG_INF)
 
     # ---- 5. dequant value + online-softmax accumulate ----------------------
-    code = jax.lax.dynamic_slice(
-        vq_ref[0], (row, 0), (1, vq_ref.shape[2]))[0]
-    vsc = jax.lax.dynamic_slice(vs_ref[0], (row, 0), (1, vs_ref.shape[2]))[0]
-    vzr = jax.lax.dynamic_slice(vz_ref[0], (row, 0), (1, vz_ref.shape[2]))[0]
-    v_tok = _dequant_token(code, vsc, vzr, v_bits, v_group).reshape(n_kv, dh)
-    m_prev = m_s[:, 0]
+    m_prev = m_s[...]
     m_new = jnp.maximum(m_prev, logits)
     p = jnp.where(logits <= NEG_INF / 2, 0.0, jnp.exp(logits - m_new))
     alpha = jnp.exp(m_prev - m_new)
-    l_s[:, 0] = l_s[:, 0] * alpha + p
-    p_g = p.reshape(n_kv, group)
-    acc_s[...] = acc_s[...] * alpha[:, None] \
-        + (p_g[:, :, None] * v_tok[:, None, :]).reshape(h, dh)
-    m_s[:, 0] = m_new
+    l_s[...] = l_s[...] * alpha + p
+    chan = _value_lanes(kvd, v_bits)
+    v = _dequant_row(vq_ref, vs_ref, vz_ref, row, chan, v_bits=v_bits,
+                     v_group=v_group)
+    head = chan // dh
+    acc_s[...] = acc_s[...] * _spread(alpha, head, n_kv) \
+        + _spread(p, head, n_kv) * v
+    m_s[...] = m_new
 
     @pl.when(n_ == nc - 1)
     def _finish():
-        m_ref[0] = m_s[:, 0]
-        l_ref[0] = l_s[:, 0]
+        m_ref[0] = m_s[...]
+        l_ref[0] = l_s[...]
         o_ref[0] = acc_s[...]
 
 
-def _fused_paged_plain(idx_ref, valid_ref, qpos_ref, base_ref, pt_ref, q_ref,
-                       lat_ref, vq_ref, vs_ref, vz_ref, u_ref, m_ref, l_ref,
-                       o_ref, m_s, l_s, acc_s, q_s, **kw):
-    _fused_paged_step(idx_ref, valid_ref, qpos_ref, base_ref, pt_ref, q_ref,
-                      lat_ref, None, vq_ref, vs_ref, vz_ref, u_ref, m_ref,
-                      l_ref, o_ref, m_s, l_s, acc_s, q_s, **kw)
-
-
-def _fused_paged_scaled(idx_ref, valid_ref, qpos_ref, base_ref, pt_ref, q_ref,
-                        lat_ref, kscale_ref, vq_ref, vs_ref, vz_ref, u_ref,
-                        m_ref, l_ref, o_ref, m_s, l_s, acc_s, q_s, **kw):
-    _fused_paged_step(idx_ref, valid_ref, qpos_ref, base_ref, pt_ref, q_ref,
-                      lat_ref, kscale_ref, vq_ref, vs_ref, vz_ref, u_ref,
-                      m_ref, l_ref, o_ref, m_s, l_s, acc_s, q_s, **kw)
-
-
-@functools.partial(jax.jit, static_argnames=("n_kv", "v_bits", "v_group",
-                                             "theta", "softcap", "use_rope",
-                                             "page_size"))
-def sparse_recon_attention_paged_pallas(
-        q: jnp.ndarray, k_lat: jnp.ndarray, k_scale: Optional[jnp.ndarray],
-        v_q: jnp.ndarray, v_scale: jnp.ndarray, v_zero: jnp.ndarray,
-        u: jnp.ndarray, idx: jnp.ndarray, valid: jnp.ndarray, q_pos, *,
-        page_table: jnp.ndarray, page_size: int,
-        n_kv: int, v_bits: int = 8, v_group: int = 64,
-        theta: float = 10_000.0, softcap: float = 0.0, use_rope: bool = True,
-        pos_base: Optional[jnp.ndarray] = None
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Paged twin of :func:`sparse_recon_attention_pallas`.
-
-    Cache operands are physical page pools (``k_lat (n_pages, ps, r)``,
-    ``v_q (n_pages, ps, code_w)``, ...); ``idx`` holds LOGICAL positions;
-    ``page_table`` (B, max_pages) rides as a 5th scalar-prefetch operand
-    and every cache index_map resolves page ``idx // ps`` through it.  One
-    grid step still processes one selected token, but the DMA unit is the
-    whole page — sorted indices make consecutive same-page steps reuse the
-    resident block (no re-DMA), so the selected-token HBM traffic is per
-    page touched.  Bit-identical to the dense kernel given the same idx
-    order (per-token math is unchanged).
-    """
-    b, h, dh = q.shape
-    ps = page_size
-    mp = page_table.shape[1]
-    nc = idx.shape[1]
-    group = h // n_kv
-    r = k_lat.shape[2]
-    code_w = v_q.shape[2]
-    g = v_scale.shape[2]
-    kvd = u.shape[0]
-
-    idx_i = idx.astype(jnp.int32)
-    valid_i = valid.astype(jnp.int32)
-    qpos_b = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32).reshape(-1), (b,))
-    base_b = jnp.zeros((b,), jnp.int32) if pos_base is None \
-        else jnp.broadcast_to(jnp.asarray(pos_base, jnp.int32), (b,))
-    pt = page_table.astype(jnp.int32)
-
-    def page_of(b_, n_, i_, pt_):
-        lp = jnp.minimum(i_[b_, n_] // ps, mp - 1)   # invalid idx: clamp
-        return jnp.clip(pt_[b_, lp], 0, k_lat.shape[0] - 1)
-
-    in_specs = [
-        pl.BlockSpec((1, h, dh),
-                     lambda b_, n_, i_, v_, p_, bb_, pt_: (b_, 0, 0)),
-        pl.BlockSpec((1, ps, r),
-                     lambda b_, n_, i_, v_, p_, bb_, pt_:
-                     (page_of(b_, n_, i_, pt_), 0, 0)),
-    ]
-    args = [q, k_lat]
-    kw = dict(n_kv=n_kv, group=group, theta=theta, softcap=softcap,
-              use_rope=use_rope, nc=nc, v_bits=v_bits, v_group=v_group,
-              ps=ps)
-    if k_scale is not None:
-        in_specs.append(
-            pl.BlockSpec((1, ps),
-                         lambda b_, n_, i_, v_, p_, bb_, pt_:
-                         (page_of(b_, n_, i_, pt_), 0)))
-        args.append(k_scale)
-        kernel = functools.partial(_fused_paged_scaled, **kw)
-    else:
-        kernel = functools.partial(_fused_paged_plain, **kw)
-    in_specs += [
-        pl.BlockSpec((1, ps, code_w),
-                     lambda b_, n_, i_, v_, p_, bb_, pt_:
-                     (page_of(b_, n_, i_, pt_), 0, 0)),
-        pl.BlockSpec((1, ps, g),
-                     lambda b_, n_, i_, v_, p_, bb_, pt_:
-                     (page_of(b_, n_, i_, pt_), 0, 0)),
-        pl.BlockSpec((1, ps, g),
-                     lambda b_, n_, i_, v_, p_, bb_, pt_:
-                     (page_of(b_, n_, i_, pt_), 0, 0)),
-        pl.BlockSpec((kvd, r),
-                     lambda b_, n_, i_, v_, p_, bb_, pt_: (0, 0)),
-    ]
-    args += [v_q, v_scale, v_zero, u]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
-        grid=(b, nc),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, h),
-                         lambda b_, n_, i_, v_, p_, bb_, pt_: (b_, 0)),
-            pl.BlockSpec((1, h),
-                         lambda b_, n_, i_, v_, p_, bb_, pt_: (b_, 0)),
-            pl.BlockSpec((1, h, dh),
-                         lambda b_, n_, i_, v_, p_, bb_, pt_: (b_, 0, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((h, 1), jnp.float32),
-            pltpu.VMEM((h, 1), jnp.float32),
-            pltpu.VMEM((h, dh), jnp.float32),
-            pltpu.VMEM((h, dh), jnp.float32),
-        ],
-    )
-    m, l, o = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, h), jnp.float32),
-            jax.ShapeDtypeStruct((b, h), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, dh), jnp.float32),
-        ],
-        interpret=_interpret(),
-    )(idx_i, valid_i, qpos_b, base_b, pt, *args)
-    return m, l, o
-
-
-def _fused_window_paged_step(idx_ref, valid_ref, qpos_ref, base_ref, pt_ref,
-                             q_ref, lat_ref, kscale_ref, vq_ref, vs_ref,
-                             vz_ref, u_ref, m_ref, l_ref, o_ref, m_s, l_s,
-                             acc_s, q_s, *, n_kv: int, group: int,
-                             theta: float, softcap: float, use_rope: bool,
-                             nc: int, v_bits: int, v_group: int, ps: int,
-                             ql: int, n_recent: int):
-    """Windowed :func:`_fused_paged_step`: whole-page DMA + one
-    reconstruction per selected token, scored by all ``ql`` queries."""
-    b_, n_ = pl.program_id(0), pl.program_id(1)
-    h, dh = q_ref.shape[2], q_ref.shape[3]
-
-    @pl.when(n_ == 0)
-    def _init():
-        m_s[...] = jnp.full_like(m_s, NEG_INF)
-        l_s[...] = jnp.zeros_like(l_s)
-        acc_s[...] = jnp.zeros_like(acc_s)
-        _window_queries(q_ref, qpos_ref, q_s, b_, ql, h, theta, use_rope)
-
-    row = jax.lax.rem(idx_ref[b_, n_], ps)                  # in-page row
-    lat = jax.lax.dynamic_slice(lat_ref[0], (row, 0), (1, lat_ref.shape[2])) \
-        .astype(jnp.float32)                                # (1, r)
-    if kscale_ref is not None:
-        sc = jax.lax.dynamic_slice(kscale_ref[0], (row,), (1,))
-        lat = lat * sc.astype(jnp.float32)
-    k_flat = jax.lax.dot_general(
-        lat, u_ref[...].astype(jnp.float32), (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)                 # (1, kvd)
-    k_pre = k_flat.reshape(n_kv, dh)
-    pos = idx_ref[b_, n_] + base_ref[b_]
-    k_r = _rope_one(k_pre, pos, theta) if use_rope else k_pre
-
-    q_g = q_s[...].reshape(ql, n_kv, group, dh)
-    logits = jnp.sum(q_g * k_r[None, :, None, :], axis=-1)  # (ql,n_kv,group)
-    code = jax.lax.dynamic_slice(
-        vq_ref[0], (row, 0), (1, vq_ref.shape[2]))[0]
-    vsc = jax.lax.dynamic_slice(vs_ref[0], (row, 0), (1, vs_ref.shape[2]))[0]
-    vzr = jax.lax.dynamic_slice(vz_ref[0], (row, 0), (1, vz_ref.shape[2]))[0]
-    v_tok = _dequant_token(code, vsc, vzr, v_bits, v_group).reshape(n_kv, dh)
-    _window_accumulate(logits, valid_ref[b_, n_], pos, qpos_ref[b_], v_tok,
-                       m_s, l_s, acc_s, ql=ql, h=h, dh=dh, n_kv=n_kv,
-                       group=group, softcap=softcap, n_recent=n_recent)
-
-    @pl.when(n_ == nc - 1)
-    def _finish():
-        m_ref[0] = m_s[:, 0].reshape(ql, h)
-        l_ref[0] = l_s[:, 0].reshape(ql, h)
-        o_ref[0] = acc_s[...].reshape(ql, h, dh)
-
-
-def _fused_window_paged_plain(idx_ref, valid_ref, qpos_ref, base_ref, pt_ref,
-                              q_ref, lat_ref, vq_ref, vs_ref, vz_ref, u_ref,
-                              m_ref, l_ref, o_ref, m_s, l_s, acc_s, q_s,
-                              **kw):
-    _fused_window_paged_step(idx_ref, valid_ref, qpos_ref, base_ref, pt_ref,
-                             q_ref, lat_ref, None, vq_ref, vs_ref, vz_ref,
-                             u_ref, m_ref, l_ref, o_ref, m_s, l_s, acc_s,
-                             q_s, **kw)
-
-
-def _fused_window_paged_scaled(idx_ref, valid_ref, qpos_ref, base_ref, pt_ref,
-                               q_ref, lat_ref, kscale_ref, vq_ref, vs_ref,
-                               vz_ref, u_ref, m_ref, l_ref, o_ref, m_s, l_s,
-                               acc_s, q_s, **kw):
-    _fused_window_paged_step(idx_ref, valid_ref, qpos_ref, base_ref, pt_ref,
-                             q_ref, lat_ref, kscale_ref, vq_ref, vs_ref,
-                             vz_ref, u_ref, m_ref, l_ref, o_ref, m_s, l_s,
-                             acc_s, q_s, **kw)
-
-
-@functools.partial(jax.jit, static_argnames=("n_kv", "n_recent", "v_bits",
-                                             "v_group", "theta", "softcap",
-                                             "use_rope", "page_size"))
-def sparse_recon_attention_window_paged_pallas(
-        q: jnp.ndarray, k_lat: jnp.ndarray, k_scale: Optional[jnp.ndarray],
-        v_q: jnp.ndarray, v_scale: jnp.ndarray, v_zero: jnp.ndarray,
-        u: jnp.ndarray, idx: jnp.ndarray, valid: jnp.ndarray, q_pos, *,
-        page_table: jnp.ndarray, page_size: int, n_kv: int,
-        n_recent: int = 0, v_bits: int = 8, v_group: int = 64,
-        theta: float = 10_000.0, softcap: float = 0.0, use_rope: bool = True,
-        pos_base: Optional[jnp.ndarray] = None
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Paged twin of :func:`sparse_recon_attention_window_pallas`: cache
-    operands are physical page pools, ``idx`` stays logical, and sorted
-    indices keep the whole-page DMA once-per-page-touched.  Bit-identical
-    to the dense windowed kernel given the same idx order."""
+def _recon_call(q, k_lat, k_scale, v_q, v_scale, v_zero, u, idx, valid,
+                q_pos, *, page_table, page_size: int, n_kv: int,
+                n_recent: int, v_bits: int, v_group: int, theta: float,
+                softcap: float, use_rope: bool, pos_base):
+    """Shared body of the four entry points.  q: (B, Q, H, dh) pre-RoPE
+    queries; returns (m (B,Q,H), l (B,Q,H), o (B,Q,H,dh)) f32 partials."""
     b, ql, h, dh = q.shape
-    ps = page_size
-    mp = page_table.shape[1]
-    nc = idx.shape[1]
+    kvd = n_kv * dh
     group = h // n_kv
-    r = k_lat.shape[2]
-    code_w = v_q.shape[2]
-    g = v_scale.shape[2]
-    kvd = u.shape[0]
+    nc = idx.shape[1]
+    r = k_lat.shape[-1]
+    paged = page_table is not None
 
-    idx_i = idx.astype(jnp.int32)
-    valid_i = valid.astype(jnp.int32)
-    qpos_b = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32).reshape(-1), (b,))
-    base_b = jnp.zeros((b,), jnp.int32) if pos_base is None \
+    qpos = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32).reshape(-1), (b,))
+    base = jnp.zeros((b,), jnp.int32) if pos_base is None \
         else jnp.broadcast_to(jnp.asarray(pos_base, jnp.int32), (b,))
-    pt = page_table.astype(jnp.int32)
+    idx_i = idx.astype(jnp.int32)
 
-    def page_of(b_, n_, i_, pt_):
-        lp = jnp.minimum(i_[b_, n_] // ps, mp - 1)   # invalid idx: clamp
-        return jnp.clip(pt_[b_, lp], 0, k_lat.shape[0] - 1)
+    # RoPE'd queries as a (Q·group, kvd) tile per row: row t·group + j,
+    # lanes of kv head g hold query head g·group + j
+    q32 = q.astype(jnp.float32)
+    if use_rope:
+        q32 = _rope(q32, qpos[:, None] + jnp.arange(ql, dtype=jnp.int32),
+                    theta)
+    q_t = q32.reshape(b, ql, n_kv, group, dh).transpose(0, 1, 3, 2, 4) \
+        .reshape(b, ql * group, kvd)
+    half = dh // 2
+    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    freq = jnp.tile(freqs, 2 * n_kv).reshape(1, kvd)
 
-    in_specs = [
-        pl.BlockSpec((1, ql, h, dh),
-                     lambda b_, n_, i_, v_, p_, bb_, pt_: (b_, 0, 0, 0)),
-        pl.BlockSpec((1, ps, r),
-                     lambda b_, n_, i_, v_, p_, bb_, pt_:
-                     (page_of(b_, n_, i_, pt_), 0, 0)),
-    ]
-    args = [q, k_lat]
-    kw = dict(n_kv=n_kv, group=group, theta=theta, softcap=softcap,
-              use_rope=use_rope, nc=nc, v_bits=v_bits, v_group=v_group,
-              ps=ps, ql=ql, n_recent=n_recent)
-    if k_scale is not None:
-        in_specs.append(
-            pl.BlockSpec((1, ps),
-                         lambda b_, n_, i_, v_, p_, bb_, pt_:
-                         (page_of(b_, n_, i_, pt_), 0)))
-        args.append(k_scale)
-        kernel = functools.partial(_fused_window_paged_scaled, **kw)
+    # per-token int8 latent scales of the selected rows -> SMEM scalars
+    if paged:
+        mp = page_table.shape[1]
+        n_pages = k_lat.shape[0]
+        pt = page_table.astype(jnp.int32)
+        tile = page_size
+        phys = jnp.clip(jnp.take_along_axis(
+            pt, jnp.minimum(idx_i // page_size, mp - 1), axis=1),
+            0, n_pages - 1)                                    # (B, N_c)
+        if k_scale is None:
+            ksel = jnp.ones((b, nc), jnp.float32)
+        else:
+            ksel = k_scale[phys, idx_i % page_size].astype(jnp.float32)
+
+        def blk(b_, n_, i_, v_, p_, bb_, ks_, pt_):
+            lp = jnp.minimum(i_[b_, n_] // page_size, mp - 1)  # invalid: clamp
+            return jnp.clip(pt_[b_, lp], 0, n_pages - 1)
+        prefetch = (idx_i, valid.astype(jnp.int32), qpos, base, ksel, pt)
     else:
-        kernel = functools.partial(_fused_window_paged_plain, **kw)
-    in_specs += [
-        pl.BlockSpec((1, ps, code_w),
-                     lambda b_, n_, i_, v_, p_, bb_, pt_:
-                     (page_of(b_, n_, i_, pt_), 0, 0)),
-        pl.BlockSpec((1, ps, g),
-                     lambda b_, n_, i_, v_, p_, bb_, pt_:
-                     (page_of(b_, n_, i_, pt_), 0, 0)),
-        pl.BlockSpec((1, ps, g),
-                     lambda b_, n_, i_, v_, p_, bb_, pt_:
-                     (page_of(b_, n_, i_, pt_), 0, 0)),
-        pl.BlockSpec((kvd, r),
-                     lambda b_, n_, i_, v_, p_, bb_, pt_: (0, 0)),
-    ]
-    args += [v_q, v_scale, v_zero, u]
+        s = k_lat.shape[1]
+        tile = math.gcd(s, _TILE)
+        if k_scale is None:
+            ksel = jnp.ones((b, nc), jnp.float32)
+        else:
+            ksel = jnp.take_along_axis(k_scale, jnp.clip(idx_i, 0, s - 1),
+                                       axis=1).astype(jnp.float32)
 
+        def blk(b_, n_, i_, v_, p_, bb_, ks_):
+            return b_, jnp.minimum(i_[b_, n_] // tile, s // tile - 1)
+        prefetch = (idx_i, valid.astype(jnp.int32), qpos, base, ksel)
+
+    def cache_spec(width):
+        if paged:
+            return pl.BlockSpec((1, tile, width),
+                                lambda *a: (blk(*a), 0, 0))
+        return pl.BlockSpec((1, tile, width), lambda *a: (*blk(*a), 0))
+
+    def whole(shape):
+        return pl.BlockSpec(shape, lambda *a: (0,) * len(shape))
+
+    def per_row(shape):
+        return pl.BlockSpec((1, *shape), lambda b_, *a: (b_,) + (0,) * len(shape))
+
+    qg = ql * group
+    kernel = functools.partial(
+        _recon_kernel, paged=paged, tile=tile, n_kv=n_kv, dh=dh, group=group,
+        softcap=softcap, use_rope=use_rope, nc=nc, v_bits=v_bits,
+        v_group=v_group, n_recent=n_recent)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
+        num_scalar_prefetch=len(prefetch),
         grid=(b, nc),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, ql, h),
-                         lambda b_, n_, i_, v_, p_, bb_, pt_: (b_, 0, 0)),
-            pl.BlockSpec((1, ql, h),
-                         lambda b_, n_, i_, v_, p_, bb_, pt_: (b_, 0, 0)),
-            pl.BlockSpec((1, ql, h, dh),
-                         lambda b_, n_, i_, v_, p_, bb_, pt_: (b_, 0, 0, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((ql * h, 1), jnp.float32),
-            pltpu.VMEM((ql * h, 1), jnp.float32),
-            pltpu.VMEM((ql * h, dh), jnp.float32),
-            pltpu.VMEM((ql * h, dh), jnp.float32),
-        ],
+        in_specs=[per_row((qg, kvd)), whole((1, kvd)), cache_spec(r),
+                  cache_spec(v_q.shape[-1]), cache_spec(v_scale.shape[-1]),
+                  cache_spec(v_zero.shape[-1]), whole(u.shape)],
+        out_specs=[per_row((qg, n_kv)), per_row((qg, n_kv)),
+                   per_row((qg, kvd))],
+        scratch_shapes=[pltpu.VMEM((qg, n_kv), jnp.float32),
+                        pltpu.VMEM((qg, n_kv), jnp.float32),
+                        pltpu.VMEM((qg, kvd), jnp.float32)],
     )
     m, l, o = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, ql, h), jnp.float32),
-            jax.ShapeDtypeStruct((b, ql, h), jnp.float32),
-            jax.ShapeDtypeStruct((b, ql, h, dh), jnp.float32),
-        ],
-        interpret=_interpret(),
-    )(idx_i, valid_i, qpos_b, base_b, pt, *args)
-    return m, l, o
+        out_shape=[jax.ShapeDtypeStruct((b, qg, n_kv), jnp.float32),
+                   jax.ShapeDtypeStruct((b, qg, n_kv), jnp.float32),
+                   jax.ShapeDtypeStruct((b, qg, kvd), jnp.float32)],
+        interpret=interpret_mode(),
+        name="sals_recon_attend_paged" if paged else "sals_recon_attend",
+    )(*prefetch, q_t, freq, k_lat, v_q, v_scale, v_zero, u)
+
+    if v_bits == 4:                 # [even | odd] lanes -> channel order
+        o = jnp.stack([o[..., :kvd // 2], o[..., kvd // 2:]], axis=-1) \
+            .reshape(b, qg, kvd)
+
+    def heads(x):                   # (B, Q·group, n_kv, ·) -> (B, Q, H, ·)
+        x = x.reshape(b, ql, group, n_kv, -1).transpose(0, 1, 3, 2, 4)
+        return x.reshape(b, ql, h, -1)
+
+    return (heads(m[..., None])[..., 0], heads(l[..., None])[..., 0],
+            heads(o.reshape(b, qg, n_kv, dh)))
 
 
-@functools.partial(jax.jit, static_argnames=("n_kv", "v_bits", "v_group",
-                                             "theta", "softcap", "use_rope"))
+_STATIC = ("n_kv", "v_bits", "v_group", "theta", "softcap", "use_rope")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def sparse_recon_attention_pallas(
         q: jnp.ndarray, k_lat: jnp.ndarray, k_scale: Optional[jnp.ndarray],
         v_q: jnp.ndarray, v_scale: jnp.ndarray, v_zero: jnp.ndarray,
@@ -763,72 +342,85 @@ def sparse_recon_attention_pallas(
     (grouped layout — RoPE is applied at ``pos_base[b] + idx[b, n]``), or
     None for 0.  Returns (m (B,H), l (B,H), o (B,H,dh)) flash partials, f32.
     """
-    b, h, dh = q.shape
-    r = k_lat.shape[2]
-    code_w = v_q.shape[2]
-    g = v_scale.shape[2]
-    kvd = u.shape[0]
-    nc = idx.shape[1]
-    group = h // n_kv
+    m, l, o = _recon_call(
+        q[:, None], k_lat, k_scale, v_q, v_scale, v_zero, u, idx, valid,
+        q_pos, page_table=None, page_size=0, n_kv=n_kv, n_recent=0,
+        v_bits=v_bits, v_group=v_group, theta=theta, softcap=softcap,
+        use_rope=use_rope, pos_base=pos_base)
+    return m[:, 0], l[:, 0], o[:, 0]
 
-    idx_i = idx.astype(jnp.int32)
-    valid_i = valid.astype(jnp.int32)
-    qpos_b = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32).reshape(-1), (b,))
-    base_b = jnp.zeros((b,), jnp.int32) if pos_base is None \
-        else jnp.broadcast_to(jnp.asarray(pos_base, jnp.int32), (b,))
 
-    in_specs = [
-        pl.BlockSpec((1, h, dh), lambda b_, n_, i_, v_, p_, bb_: (b_, 0, 0)),
-        pl.BlockSpec((1, 1, r),
-                     lambda b_, n_, i_, v_, p_, bb_: (b_, i_[b_, n_], 0)),
-    ]
-    args = [q, k_lat]
-    kw = dict(n_kv=n_kv, group=group, theta=theta, softcap=softcap,
-              use_rope=use_rope, nc=nc, v_bits=v_bits, v_group=v_group)
-    if k_scale is not None:
-        in_specs.append(
-            pl.BlockSpec((1, 1),
-                         lambda b_, n_, i_, v_, p_, bb_: (b_, i_[b_, n_])))
-        args.append(k_scale)
-        kernel = functools.partial(_fused_kernel_scaled, **kw)
-    else:
-        kernel = functools.partial(_fused_kernel_plain, **kw)
-    in_specs += [
-        pl.BlockSpec((1, 1, code_w),
-                     lambda b_, n_, i_, v_, p_, bb_: (b_, i_[b_, n_], 0)),
-        pl.BlockSpec((1, 1, g),
-                     lambda b_, n_, i_, v_, p_, bb_: (b_, i_[b_, n_], 0)),
-        pl.BlockSpec((1, 1, g),
-                     lambda b_, n_, i_, v_, p_, bb_: (b_, i_[b_, n_], 0)),
-        pl.BlockSpec((kvd, r), lambda b_, n_, i_, v_, p_, bb_: (0, 0)),
-    ]
-    args += [v_q, v_scale, v_zero, u]
+@functools.partial(jax.jit, static_argnames=_STATIC + ("page_size",))
+def sparse_recon_attention_paged_pallas(
+        q: jnp.ndarray, k_lat: jnp.ndarray, k_scale: Optional[jnp.ndarray],
+        v_q: jnp.ndarray, v_scale: jnp.ndarray, v_zero: jnp.ndarray,
+        u: jnp.ndarray, idx: jnp.ndarray, valid: jnp.ndarray, q_pos, *,
+        page_table: jnp.ndarray, page_size: int,
+        n_kv: int, v_bits: int = 8, v_group: int = 64,
+        theta: float = 10_000.0, softcap: float = 0.0, use_rope: bool = True,
+        pos_base: Optional[jnp.ndarray] = None
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Paged twin of :func:`sparse_recon_attention_pallas`.
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(b, nc),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, h), lambda b_, n_, i_, v_, p_, bb_: (b_, 0)),
-            pl.BlockSpec((1, h), lambda b_, n_, i_, v_, p_, bb_: (b_, 0)),
-            pl.BlockSpec((1, h, dh),
-                         lambda b_, n_, i_, v_, p_, bb_: (b_, 0, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((h, 1), jnp.float32),
-            pltpu.VMEM((h, 1), jnp.float32),
-            pltpu.VMEM((h, dh), jnp.float32),
-            pltpu.VMEM((h, dh), jnp.float32),
-        ],
-    )
-    m, l, o = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, h), jnp.float32),
-            jax.ShapeDtypeStruct((b, h), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, dh), jnp.float32),
-        ],
-        interpret=_interpret(),
-    )(idx_i, valid_i, qpos_b, base_b, *args)
-    return m, l, o
+    Cache operands are physical page pools (``k_lat (n_pages, ps, r)``,
+    ``v_q (n_pages, ps, code_w)``, ...); ``idx`` holds LOGICAL positions;
+    ``page_table`` (B, max_pages) rides as a scalar-prefetch operand and
+    every cache index_map resolves page ``idx // ps`` through it.  The DMA
+    unit is the whole page — sorted indices make consecutive same-page
+    steps reuse the resident block, so the selected-token HBM traffic is
+    per page touched.  Bit-identical to the dense kernel given the same
+    idx order (per-token math is unchanged).
+    """
+    m, l, o = _recon_call(
+        q[:, None], k_lat, k_scale, v_q, v_scale, v_zero, u, idx, valid,
+        q_pos, page_table=page_table, page_size=page_size, n_kv=n_kv,
+        n_recent=0, v_bits=v_bits, v_group=v_group, theta=theta,
+        softcap=softcap, use_rope=use_rope, pos_base=pos_base)
+    return m[:, 0], l[:, 0], o[:, 0]
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC + ("n_recent",))
+def sparse_recon_attention_window_pallas(
+        q: jnp.ndarray, k_lat: jnp.ndarray, k_scale: Optional[jnp.ndarray],
+        v_q: jnp.ndarray, v_scale: jnp.ndarray, v_zero: jnp.ndarray,
+        u: jnp.ndarray, idx: jnp.ndarray, valid: jnp.ndarray, q_pos, *,
+        n_kv: int, n_recent: int = 0, v_bits: int = 8, v_group: int = 64,
+        theta: float = 10_000.0, softcap: float = 0.0, use_rope: bool = True,
+        pos_base: Optional[jnp.ndarray] = None
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Windowed fused decode attention (speculative verify window).
+
+    q: (B, q_len, H, dh) pre-RoPE queries; ``q_pos`` (scalar or (B,)) is
+    the WINDOW BASE — query t is RoPE'd at ``q_pos + t``.  One selected
+    set (idx/valid) serves the whole window: each token is reconstructed
+    once and attended by all queries, with the per-draft-position mask
+    advance applied in-kernel (``n_recent`` static; see module docstring).
+    Returns (m (B,Q,H), l (B,Q,H), o (B,Q,H,dh)) f32 partials.
+    """
+    return _recon_call(
+        q, k_lat, k_scale, v_q, v_scale, v_zero, u, idx, valid, q_pos,
+        page_table=None, page_size=0, n_kv=n_kv, n_recent=n_recent,
+        v_bits=v_bits, v_group=v_group, theta=theta, softcap=softcap,
+        use_rope=use_rope, pos_base=pos_base)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=_STATIC + ("n_recent", "page_size"))
+def sparse_recon_attention_window_paged_pallas(
+        q: jnp.ndarray, k_lat: jnp.ndarray, k_scale: Optional[jnp.ndarray],
+        v_q: jnp.ndarray, v_scale: jnp.ndarray, v_zero: jnp.ndarray,
+        u: jnp.ndarray, idx: jnp.ndarray, valid: jnp.ndarray, q_pos, *,
+        page_table: jnp.ndarray, page_size: int, n_kv: int,
+        n_recent: int = 0, v_bits: int = 8, v_group: int = 64,
+        theta: float = 10_000.0, softcap: float = 0.0, use_rope: bool = True,
+        pos_base: Optional[jnp.ndarray] = None
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Paged twin of :func:`sparse_recon_attention_window_pallas`: cache
+    operands are physical page pools, ``idx`` stays logical, and sorted
+    indices keep the whole-page DMA once-per-page-touched.  Bit-identical
+    to the dense windowed kernel given the same idx order."""
+    return _recon_call(
+        q, k_lat, k_scale, v_q, v_scale, v_zero, u, idx, valid, q_pos,
+        page_table=page_table, page_size=page_size, n_kv=n_kv,
+        n_recent=n_recent, v_bits=v_bits, v_group=v_group, theta=theta,
+        softcap=softcap, use_rope=use_rope, pos_base=pos_base)

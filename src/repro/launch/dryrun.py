@@ -1,6 +1,9 @@
 import os
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=512").strip()
+# a compile-on-host tool: never claim an attached accelerator (rungrid
+# starts several of these at once, and a chip takes one process)
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 # Multi-pod dry-run: lower + compile every (arch × shape) cell on the
 # production mesh and record memory/cost/collective analysis.
@@ -8,8 +11,9 @@ os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
 #     PYTHONPATH=src python -m repro.launch.dryrun --arch yi-9b \
 #         --shape decode_32k [--multi-pod] [--out artifacts/dryrun]
 #
-# The XLA_FLAGS assignment above is the VERY FIRST statement — before ANY
-# other import — because jax locks the device count on first init; nothing
+# The XLA_FLAGS / JAX_PLATFORMS assignments above are the VERY FIRST
+# statements — before ANY other import — because jax locks the platform and
+# device count on first init; nothing
 # else in the repo sets it globally (smoke tests/benches see 1 device).
 
 import argparse

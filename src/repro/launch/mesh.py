@@ -16,12 +16,13 @@ from __future__ import annotations
 import jax
 
 from repro.config import MeshConfig
+from repro.distributed.sharding import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh_config(*, multi_pod: bool = False, dist_mode: str = "local",
@@ -40,4 +41,4 @@ def make_host_mesh(max_devices: int = 0):
     n = len(jax.devices())
     if max_devices:
         n = min(n, max_devices)
-    return jax.make_mesh((1, n), ("data", "model"))
+    return make_mesh((1, n), ("data", "model"))

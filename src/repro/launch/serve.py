@@ -2,15 +2,20 @@
 
     PYTHONPATH=src python -m repro.launch.serve --arch yi-9b --reduced \
         --requests 8 --max-new-tokens 32 [--sals 0.25|0.125|off]
+    PYTHONPATH=src python -m repro.launch.serve --arch paper-mistral-7b \
+        --full --layers 8 --max-seq 4096      # published widths, 8 layers
 
 Trains nothing: weights are random unless ``--ckpt`` points at a training
 checkpoint.  Calibrates the SALS projector on the synthetic corpus (paper
 §5.1), builds the engine, runs a batch of requests through the scheduler
-and reports tokens/s.
+and reports tokens/s.  :func:`build` is the one construction path
+(``chip_smoke.py`` serves through it too).
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import os
 import time
 
 import jax
@@ -18,7 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import checkpoint as ckpt_store
-from repro.config import SALSConfig, ServeConfig, TrainConfig
+from repro.config import ModelConfig, SALSConfig, ServeConfig, TrainConfig
 from repro.configs import ASSIGNED_ARCHS, PAPER_ARCHS, get_config
 from repro.core import calibration as cal
 from repro.data import CalibrationSampler, SyntheticCorpus
@@ -32,11 +37,12 @@ def calibrate(params, cfg, sals, corpus, n_sequences=16, seq_len=128):
     sampler = CalibrationSampler(corpus, n_sequences=n_sequences,
                                  seq_len=seq_len, batch_size=4)
 
-    @jax.jit
-    def key_fn(tokens):
-        return collect_pre_rope_keys(params, cfg, {"tokens": tokens})
-
-    keys = cal.collect_keys(key_fn, sampler.batches(),
+    # params are an argument, not a closure: a jitted closure would bake
+    # every weight into the program as a constant
+    key_fn = jax.jit(lambda p, tokens: collect_pre_rope_keys(
+        p, cfg, {"tokens": tokens}))
+    keys = cal.collect_keys(lambda tokens: key_fn(params, tokens),
+                            sampler.batches(),
                             max_tokens=n_sequences * seq_len)
     return cal.fit_layer_projectors(keys, sals.rank(cfg.kv_dim))
 
@@ -61,12 +67,79 @@ def collect_pre_rope_keys(params, cfg, batch):
     return ks
 
 
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is what JAX already uses and
+    nothing else is set here; otherwise the cache is the fixed
+    ``.jax_cache/`` at the repository root (git-ignored).  The path is part
+    of the cache key, so it never depends on a temp name, pid or time."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(REPO_ROOT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def model_config(arch: str, *, reduced: bool = False,
+                 layers: int = 0) -> ModelConfig:
+    """``arch``'s config.  ``reduced`` shrinks every width (CPU smoke
+    runs); ``layers`` keeps the widths and cuts only the depth."""
+    cfg = get_config(arch)
+    if reduced:
+        cfg = cfg.reduced()
+    if layers:
+        if not 0 < layers <= cfg.n_layers:
+            raise ValueError(f"--layers {layers} outside [1, {cfg.n_layers}]")
+        cfg = dataclasses.replace(cfg, n_layers=layers)
+    return cfg
+
+
+def build(cfg: ModelConfig, scfg: ServeConfig, *, seed: int = 0,
+          ckpt: str = "", n_groups: int = 1, params=None, projectors=None,
+          log=print):
+    """Params, SALS projectors, engine and scheduler for one ServeConfig.
+
+    Params are random from ``seed`` (or restored from ``ckpt``) in
+    ``cfg.dtype``; the projectors are calibrated on the synthetic corpus
+    when SALS is on.  Pass ``params``/``projectors`` from an earlier build
+    to serve another ServeConfig over the same model.  Returns
+    (RequestScheduler, params, projectors)."""
+    dtype = jnp.dtype(cfg.dtype)
+    if params is None:
+        key = jax.random.PRNGKey(seed)
+        if ckpt:
+            state = trainer.init_state(key, cfg, TrainConfig(), jnp.float32)
+            state, step = ckpt_store.restore(ckpt, state)
+            params = jax.tree.map(lambda a: a.astype(dtype), state["params"])
+            log(f"[serve] loaded checkpoint step {step}")
+        else:
+            params = tf.init_params(key, cfg, dtype)
+    sals = scfg.sals if scfg.sals.enabled and cfg.has_attention else None
+    if projectors is None and sals is not None:
+        t0 = time.time()
+        projectors = calibrate(params, cfg, sals,
+                               SyntheticCorpus(cfg.vocab_size, seed=seed))
+        log(f"[serve] calibrated projectors in {time.time()-t0:.1f}s "
+            f"(rank {sals.rank(cfg.kv_dim)}/{cfg.kv_dim})")
+    engine = ServeEngine(params, projectors, cfg, scfg,
+                         n_groups=n_groups)  # validates divisibility
+    return RequestScheduler(engine), params, projectors
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True,
                     choices=ASSIGNED_ARCHS + PAPER_ARCHS)
     ap.add_argument("--reduced", action="store_true", default=True)
     ap.add_argument("--full", dest="reduced", action="store_false")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="serve only the first N layers (0 = all); with "
+                         "--full the widths stay the published ones")
     ap.add_argument("--sals", default="0.25",
                     choices=("0.25", "0.125", "off"))
     ap.add_argument("--requests", type=int, default=8)
@@ -210,23 +283,13 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
-    cfg = get_config(args.arch)
-    if args.reduced:
-        cfg = cfg.reduced()
+    use_compile_cache()
+    cfg = model_config(args.arch, reduced=args.reduced, layers=args.layers)
     if not cfg.is_decoder:
         raise SystemExit(f"{args.arch} is encoder-only — no serving path")
 
-    key = jax.random.PRNGKey(args.seed)
-    params = tf.init_params(key, cfg, jnp.float32)
-    if args.ckpt:
-        state = trainer.init_state(key, cfg, TrainConfig(), jnp.float32)
-        state, step = ckpt_store.restore(args.ckpt, state)
-        params = state["params"]
-        print(f"[serve] loaded checkpoint step {step}")
-
     corpus = SyntheticCorpus(cfg.vocab_size, seed=args.seed)
     sals = None
-    projectors = None
     if args.sals != "off" and cfg.has_attention:
         sals = SALSConfig(
             rank_ratio=float(args.sals),
@@ -234,10 +297,6 @@ def main() -> None:
             n_critical=64, n_sink=4, n_recent=16,
             v_group=min(32, cfg.kv_dim),
             skip_layers_front=min(2, cfg.n_layers - 1), skip_layers_back=1)
-        t0 = time.time()
-        projectors = calibrate(params, cfg, sals, corpus)
-        print(f"[serve] calibrated projectors in {time.time()-t0:.1f}s "
-              f"(rank {sals.rank(cfg.kv_dim)}/{cfg.kv_dim})")
 
     if args.page_size and (sals is None or not cfg.has_attention):
         raise SystemExit("--page-size needs SALS latent segments "
@@ -278,9 +337,8 @@ def main() -> None:
         obs_handles = obs.enable(
             gauge_history=args.gauge_history, cfg=cfg, sals=sals,
             with_traffic=sals is not None and cfg.has_attention)
-    engine = ServeEngine(params, projectors, cfg, scfg,
-                         n_groups=args.groups)  # validates divisibility
-    sched = RequestScheduler(engine)
+    sched, _, _ = build(cfg, scfg, seed=args.seed, ckpt=args.ckpt,
+                        n_groups=args.groups)
 
     def write_metrics(path):
         from repro.obs import metrics as obs_metrics

@@ -47,6 +47,27 @@ from repro.models import transformer as tf
 from repro.serve.faults import maybe_fault
 
 
+class _WeightsJit:
+    """``jax.jit`` of a step whose first argument is the model weights,
+    called without them: every call passes ``weights()``.  The weights are
+    arguments, never captured by a closure — a jitted closure over a
+    full-width model bakes gigabytes of constants into the program."""
+
+    def __init__(self, fn, weights, donate_argnums=()):
+        self._jit = jax.jit(fn, donate_argnums=tuple(
+            i + 1 for i in donate_argnums))
+        self._weights = weights
+
+    def __call__(self, *args):
+        return self._jit(self._weights(), *args)
+
+    def lower(self, *args):
+        return self._jit.lower(self._weights(), *args)
+
+    def _cache_size(self) -> int:
+        return self._jit._cache_size()
+
+
 @dataclasses.dataclass
 class GenerationResult:
     tokens: np.ndarray          # (new_tokens,) generated ids
@@ -150,18 +171,23 @@ class ServeEngine:
                 raise ValueError(
                     f"pool of {scfg.pool_pages} pages cannot hold one "
                     f"max_seq_len {scfg.max_seq_len} sequence")
-        self._prefill = jax.jit(self._prefill_impl)
-        self._prefill_chunk = jax.jit(self._prefill_chunk_impl,
-                                      donate_argnums=(1, 2))
+        def weighted(fn, donate_argnums=()):
+            return _WeightsJit(fn, lambda: (self.params, self.projectors),
+                               donate_argnums)
+
+        self._prefill = weighted(self._prefill_impl)
+        self._prefill_chunk = weighted(self._prefill_chunk_impl,
+                                       donate_argnums=(1, 2))
         self._init_prefill = jax.jit(self._init_prefill_impl)
-        self._decode = jax.jit(self._decode_impl, donate_argnums=(1,))
-        self._decode_sel = jax.jit(self._decode_sel_impl, donate_argnums=(1,))
+        self._decode = weighted(self._decode_impl, donate_argnums=(1,))
+        self._decode_sel = weighted(self._decode_sel_impl,
+                                    donate_argnums=(1,))
         # speculative verify window (ISSUE 9): the window forward reads the
         # cache WITHOUT donating it (the same buffers are re-read by the
         # commit), then the commit donates cache + window K/V
-        self._decode_window = jax.jit(self._decode_window_impl)
-        self._commit_window = jax.jit(self._commit_window_impl,
-                                      donate_argnums=(0,))
+        self._decode_window = weighted(self._decode_window_impl)
+        self._commit_window = weighted(self._commit_window_impl,
+                                       donate_argnums=(0,))
         if scfg.spec_window > 1 and not self.ragged_ok:
             raise ValueError(
                 f"{cfg.family} decode carries recurrent state — a rejected "
@@ -205,13 +231,14 @@ class ServeEngine:
 
     # -- jitted bodies -------------------------------------------------------
 
-    def _prefill_impl(self, batch, lengths=None):
-        return tf.prefill(self.params, self.projectors, self.cfg, self.sals,
+    def _prefill_impl(self, weights, batch, lengths=None):
+        return tf.prefill(*weights, self.cfg, self.sals,
                           batch, self.scfg.max_seq_len,
                           n_groups=self.n_groups, lengths=lengths)
 
-    def _prefill_chunk_impl(self, tokens, cache, scratch, off, lengths):
-        return tf.prefill_chunk(self.params, self.projectors, self.cfg,
+    def _prefill_chunk_impl(self, weights, tokens, cache, scratch, off,
+                            lengths):
+        return tf.prefill_chunk(*weights, self.cfg,
                                 self.sals, cache, scratch,
                                 {"tokens": tokens}, off, lengths)
 
@@ -222,17 +249,17 @@ class ServeEngine:
                                           self.scfg.max_seq_len)
         return cache, scratch
 
-    def _decode_impl(self, tokens, cache, pos):
-        return tf.decode_step(self.params, self.projectors, cache, tokens,
+    def _decode_impl(self, weights, tokens, cache, pos):
+        return tf.decode_step(*weights, cache, tokens,
                               pos, self.cfg, self.sals)
 
-    def _decode_sel_impl(self, tokens, cache, pos):
+    def _decode_sel_impl(self, weights, tokens, cache, pos):
         """Decode step that also reports WHICH logical pages the SALS
         selection reconstructed from, unioned over layers/segments to one
         (B, max_pages) bool mask — the tiered fetch-and-rerun loop's
         residency probe (see RequestScheduler._tiered_decode)."""
         logits, cache, touched = tf.decode_step(
-            self.params, self.projectors, cache, tokens, pos, self.cfg,
+            *weights, cache, tokens, pos, self.cfg,
             self.sals, collect_selection=True)
         union = None
         for seg_touch in touched.values():         # (ls, B, max_pages)
@@ -246,12 +273,12 @@ class ServeEngine:
             union = jnp.zeros((tokens.shape[0], mp), bool)
         return logits, cache, union
 
-    def _decode_window_impl(self, tokens, cache, pos):
-        return tf.decode_window(self.params, self.projectors, cache, tokens,
+    def _decode_window_impl(self, weights, tokens, cache, pos):
+        return tf.decode_window(*weights, cache, tokens,
                                 pos, self.cfg, self.sals)
 
-    def _commit_window_impl(self, cache, aux, pos, n_accept):
-        return tf.commit_window(self.projectors, cache, aux, pos, n_accept,
+    def _commit_window_impl(self, weights, cache, aux, pos, n_accept):
+        return tf.commit_window(weights[1], cache, aux, pos, n_accept,
                                 self.cfg, self.sals)
 
     def _admit_impl(self, cache, one, slot):

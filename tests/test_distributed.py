@@ -21,6 +21,7 @@ def run_sub(body: str, devices: int = 8, timeout: int = 900) -> str:
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={devices}"
         import jax, jax.numpy as jnp
         import numpy as np
+        from repro.distributed.sharding import make_mesh
     """) + textwrap.dedent(body)
     proc = subprocess.run(
         [sys.executable, "-c", code], capture_output=True, text=True,
@@ -51,7 +52,7 @@ def test_sharded_train_step_matches_single_device():
         s1, m1 = jax.jit(step)(state, batch)
 
         # 2x4 mesh with logical rules
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         mesh_cfg = MeshConfig(shape=(2, 4), axis_names=("data", "model"),
                               seq_parallel=False)
         rules = default_rules(mesh_cfg, ShapeConfig("t", "train", 64, 8))
@@ -71,10 +72,9 @@ def test_sharded_train_step_matches_single_device():
 def test_compressed_grads_close_to_exact_and_ef_accumulates():
     out = run_sub("""
         from repro.distributed import compression as gc
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         g_global = jax.random.normal(jax.random.PRNGKey(0), (8, 128, 64),
                                      jnp.float32)
 
@@ -83,10 +83,10 @@ def test_compressed_grads_close_to_exact_and_ef_accumulates():
                 {"w": g[0]}, {"w": r[0]}, ("data",))
             return mean["w"], new_r["w"]
 
-        gs = shard_map(body, mesh=mesh,
+        gs = jax.shard_map(body, mesh=mesh,
                        in_specs=(P("data"), P("data")),
                        out_specs=(P(), P("data")),
-                       check_rep=False)
+                       check_vma=False)
         r0 = jnp.zeros_like(g_global)
         mean, r1 = gs(g_global, r0)
         exact = jnp.mean(g_global, axis=0)
@@ -101,10 +101,10 @@ def test_compressed_grads_close_to_exact_and_ef_accumulates():
             mean, new_r = gc.compressed_mean_grads(
                 {"w": g[0]}, {"w": r[0]}, ("data",))
             return mean["w"], new_r["w"]
-        m2, _ = shard_map(body2, mesh=mesh,
+        m2, _ = jax.shard_map(body2, mesh=mesh,
                           in_specs=(P("data"), P("data")),
                           out_specs=(P(), P("data")),
-                          check_rep=False)(tiny, jnp.zeros_like(tiny))
+                          check_vma=False)(tiny, jnp.zeros_like(tiny))
         # psum's reduction order differs from jnp.mean's by f32 associativity
         np.testing.assert_allclose(np.asarray(m2),
                                    np.asarray(jnp.mean(tiny, 0)), rtol=1e-5)
@@ -123,7 +123,7 @@ def test_grouped_topk_decode_matches_global_on_mesh():
         from repro.launch import specs as sp
 
         cfg = get_config("yi-9b").reduced(n_layers=3, vocab_size=512)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         mesh_cfg = MeshConfig(shape=(2, 4), axis_names=("data", "model"))
         shape = ShapeConfig("d", "decode", 256, 8)
 
@@ -179,7 +179,7 @@ def test_elastic_restore_across_device_counts(tmp_path):
         from repro.models import transformer as tf
         cfg = get_config("qwen2-1.5b").reduced(n_layers=2, vocab_size=256)
         params = tf.init_params(jax.random.PRNGKey(7), cfg, jnp.float32)
-        mesh = jax.make_mesh((4,), ("model",))
+        mesh = make_mesh((4,), ("model",))
         sh = jax.tree.map(lambda p: NamedSharding(
             mesh, P("model") if p.shape[0] % 4 == 0 else P()), params)
         params = jax.tree.map(jax.device_put, params, sh)
@@ -197,7 +197,7 @@ def test_elastic_restore_across_device_counts(tmp_path):
         cfg = get_config("qwen2-1.5b").reduced(n_layers=2, vocab_size=256)
         like = {{"params": tf.init_params(jax.random.PRNGKey(7), cfg,
                                           jnp.float32)}}
-        mesh = jax.make_mesh((8,), ("model",))
+        mesh = make_mesh((8,), ("model",))
         sh = jax.tree.map(lambda p: NamedSharding(
             mesh, P("model") if p.shape[0] % 8 == 0 else P()), like)
         restored, step = ckpt.restore({ck!r}, like, shardings=sh)

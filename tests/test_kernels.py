@@ -19,6 +19,33 @@ def tol(dtype):
 
 
 # ---------------------------------------------------------------------------
+# backend dispatch and interpret mode follow the platform
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("platform,backend,interpret", [
+    ("tpu", "pallas", False), ("cpu", "xla", True), ("gpu", "xla", None)])
+def test_backend_and_interpret_follow_platform(monkeypatch, platform,
+                                               backend, interpret):
+    """A TPU runs the Pallas kernels compiled; only the CPU interprets
+    them; any other platform refuses to interpret (it would time the
+    interpreter).  An explicit backend overrides the platform until reset."""
+    from repro.kernels import interpret_mode
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    assert ops.default_backend() == backend
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="neither"):
+            interpret_mode()
+    else:
+        assert interpret_mode() is interpret
+    ops.set_default_backend("naive")
+    try:
+        assert ops.default_backend() == "naive"
+    finally:
+        ops.set_default_backend(None)
+    assert ops.default_backend() == backend
+
+
+# ---------------------------------------------------------------------------
 # flash attention
 # ---------------------------------------------------------------------------
 

@@ -13,6 +13,7 @@ def run_sub(body: str, devices: int = 4, timeout: int = 900) -> str:
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={devices}"
         import jax, jax.numpy as jnp
         import numpy as np
+        from repro.distributed.sharding import make_mesh
     """) + textwrap.dedent(body)
     proc = subprocess.run(
         [sys.executable, "-c", code], capture_output=True, text=True,
@@ -24,7 +25,6 @@ def run_sub(body: str, devices: int = 4, timeout: int = 900) -> str:
 
 def test_gpipe_matches_reference_loss_and_grads():
     out = run_sub("""
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.configs import get_config
         from repro.data import SyntheticCorpus
@@ -55,7 +55,7 @@ def test_gpipe_matches_reference_loss_and_grads():
         ref, ref_grads = jax.value_and_grad(ref_loss)(params)
 
         # pipeline: 4 stages x 1 layer (full-manual 1-D pipe mesh)
-        mesh = jax.make_mesh((4,), ("pipe",))
+        mesh = make_mesh((4,), ("pipe",))
         n_stages = 4
 
         def block_fn(stage_blocks, x):
@@ -86,11 +86,11 @@ def test_gpipe_matches_reference_loss_and_grads():
             staged = jax.tree.map(
                 lambda a: a.reshape(n_stages, a.shape[0] // n_stages,
                                     *a.shape[1:]), blocks)
-            f = shard_map(
+            f = jax.shard_map(
                 pl, mesh=mesh,
                 in_specs=(P("pipe"), P(), P()),
                 out_specs=P(),
-                check_rep=False)
+                check_vma=False)
             return f(staged, io_params, batch)
 
         val, grads = jax.value_and_grad(pipelined, argnums=(0, 1))(

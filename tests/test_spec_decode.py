@@ -171,12 +171,26 @@ def test_window_mask_advance_equals_shifted_single(backend, ql):
     """Per-draft-position mask advance: with the SAME selection, window
     query t must equal a q_len = 1 windowed call at base q_pos + t with the
     same n_recent — the window is Q shifted single-token attends sharing
-    one reconstruction."""
+    one reconstruction.
+
+    The Pallas kernel runs the same per-row body at every q_len, so it
+    must match bit for bit.  The jnp oracle compiles the Q-query window
+    and the one-query call as two XLA programs with different dot shapes
+    (a batched gemm against the gemv of its q_len = 1 delegate); their
+    f32 accumulation orders differ by a few ulps over the N_c-term sums,
+    so the oracle is held to 1e-5 instead."""
     b, s = 2, 160
     n_kv, dh, h = 2, 32, 4
     r, r_star, nc, vg, n_rec = 16, 8, 24, 16, 8
     q, k_lat, k_scale, vq, u, q_lat = _win_inputs(
         b, s, r, r_star, nc, n_kv, dh, h, ql, k_int8=True, seed=11)
+
+    def same(x, y, t):
+        if backend == "pallas":
+            assert np.array_equal(np.asarray(x), np.asarray(y)), t
+        else:
+            np.testing.assert_allclose(np.asarray(x), np.asarray(y),
+                                       rtol=1e-5, atol=1e-5, err_msg=str(t))
     pos = jnp.asarray([140, 60], jnp.int32)
     idx, valid = ops.latent_topk(q_lat, k_lat, k_scale, pos + ql - 1,
                                  n_critical=nc, n_sink=2, n_recent=n_rec,
@@ -190,9 +204,9 @@ def test_window_mask_advance_equals_shifted_single(backend, ql):
             q[:, t:t + 1], k_lat, k_scale, vq["q"], vq["scale"], vq["zero"],
             u, idx, valid, pos + t, n_kv=n_kv, n_recent=n_rec, v_bits=8,
             v_group=vg, backend=backend)
-        assert np.array_equal(np.asarray(mw[:, t]), np.asarray(m1[:, 0])), t
-        assert np.array_equal(np.asarray(lw[:, t]), np.asarray(l1[:, 0])), t
-        assert np.array_equal(np.asarray(ow[:, t]), np.asarray(o1[:, 0])), t
+        same(mw[:, t], m1[:, 0], t)
+        same(lw[:, t], l1[:, 0], t)
+        same(ow[:, t], o1[:, 0], t)
 
 
 @pytest.mark.parametrize("ql", [2, 4])

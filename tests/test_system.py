@@ -16,7 +16,8 @@ from repro.core import calibration as cal
 from repro.core import metrics
 from repro.data import SyntheticCorpus, make_batches
 from repro.ft import StragglerMonitor, Supervisor
-from repro.launch.serve import calibrate, collect_pre_rope_keys
+from repro.launch.serve import (build, calibrate, collect_pre_rope_keys,
+                                model_config)
 from repro.models import transformer as tf
 from repro.serve import Request, RequestScheduler, ServeEngine
 from repro.train import trainer
@@ -125,6 +126,63 @@ def test_scheduler_batches_and_completes(trained):
     assert len(done) == 7
     for r in done:
         assert r.done and len(r.result.tokens) == r.max_new_tokens
+
+
+# ---------------------------------------------------------------------------
+# launcher construction path (launch/serve.py, shared with chip_smoke.py)
+# ---------------------------------------------------------------------------
+
+def test_model_config_cuts_depth_keeps_published_widths():
+    full = get_config("paper-mistral-7b")
+    cut = model_config("paper-mistral-7b", layers=8)
+    assert cut.n_layers == 8
+    assert (cut.d_model, cut.n_heads, cut.n_kv_heads, cut.head_dim,
+            cut.d_ff, cut.vocab_size, cut.dtype) == \
+        (full.d_model, full.n_heads, full.n_kv_heads, full.head_dim,
+         full.d_ff, full.vocab_size, full.dtype) == \
+        (4096, 32, 8, 128, 14336, 32000, "bfloat16")
+    assert model_config("paper-mistral-7b", reduced=True).d_model == 128
+    for bad in (-1, full.n_layers + 1):
+        with pytest.raises(ValueError, match="--layers"):
+            model_config("paper-mistral-7b", layers=bad)
+
+
+def test_build_serves_with_params_in_config_dtype():
+    """``build`` makes params in ``cfg.dtype`` (bf16), calibrates the SALS
+    projectors, and its scheduler serves; a second build reuses both."""
+    cfg = model_config("paper-mistral-7b", reduced=True, layers=3)
+    sals = SALSConfig(rank_ratio=0.5, n_critical=16, n_sink=2, n_recent=8,
+                      v_group=32, skip_layers_front=1, skip_layers_back=1)
+    scfg = ServeConfig(max_seq_len=64, max_new_tokens=4, max_batch=2,
+                       sals=sals)
+    sched, params, proj = build(cfg, scfg, log=lambda _: None)
+    assert {a.dtype for a in jax.tree.leaves(params)} == {jnp.dtype(cfg.dtype)}
+    assert proj is not None
+    prompt = SyntheticCorpus(cfg.vocab_size, seed=1).batch(0, 1, 40)["tokens"]
+    sched.submit(Request(prompt[0], max_new_tokens=4))
+    (r,) = sched.run()
+    assert r.done and len(r.result.tokens) == 4
+    again, p2, proj2 = build(cfg, scfg, params=params, projectors=proj)
+    assert p2 is params and proj2 is proj and again is not sched
+
+
+def test_use_compile_cache_follows_env_else_repo_dir(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; without it the cache is
+    the fixed, git-ignored .jax_cache/ at the repo root."""
+    from repro.launch import serve as launch_serve
+    was = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    try:
+        assert launch_serve.use_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == was
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = launch_serve.use_compile_cache()
+        assert path == os.path.join(launch_serve.REPO_ROOT, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+    with open(os.path.join(launch_serve.REPO_ROOT, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
 
 
 # ---------------------------------------------------------------------------
