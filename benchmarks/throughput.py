@@ -401,7 +401,7 @@ def speculative_rows():
 
 # telemetry seams the scheduler/engine hot loop consults per decode step
 # when NOTHING is installed: tracer-is-None at the span sites (lifecycle,
-# decode_step, transfers), traffic-is-None, _metrics_installed, the pager
+# decode_step, transfers), _metrics_installed, the pager
 # hook, engine decode_throughput's tracer check.  Counted generously (the
 # real loop visits fewer on most steps).
 _OBS_SEAMS_PER_STEP = 16
@@ -418,10 +418,9 @@ def obs_overhead_rows():
     hundreds of trials.  Both cells therefore measure ATTRIBUTION inside
     one drain, where numerator and denominator share the same noise:
 
-    * "enabled": the full stack (registry + tracer + traffic accountant
-      reconciling every decode step) runs while every telemetry entry
-      point the scheduler/engine calls (span begin/end, observe_decode /
-      observe_transfer, gauge publishing) is wrapped with a
+    * "enabled": the full stack (registry + tracer) runs while every
+      telemetry entry point the scheduler/engine calls (span begin/end,
+      gauge publishing) is wrapped with a
       ``perf_counter`` pair; overhead_pct = telemetry seconds / drain
       seconds.  The wrapper cost lands in the numerator, so the measured
       number is an overestimate — conservative in the right direction.
@@ -466,7 +465,7 @@ def obs_overhead_rows():
 
     for _ in range(6):                       # warm HLOs + engine caches
         drain()
-    with obs.enabled(cfg=cfg, sals=sals, with_traffic=True):
+    with obs.enabled():
         drain()                              # warm telemetry one-timers
 
     # -- disabled: measured guard cost × seam visits -----------------------
@@ -497,18 +496,16 @@ def obs_overhead_rows():
         return w
 
     def wrap(sched):
-        tr, acct = sched.tracer, sched.traffic
+        tr = sched.tracer
         tr.begin = timed(tr.begin)
         tr.end = timed(tr.end)
         tr.end_track = timed(tr.end_track)
         tr.instant = timed(tr.instant)
-        acct.observe_decode = timed(acct.observe_decode)
-        acct.observe_transfer = timed(acct.observe_transfer)
         sched._publish_gauges = timed(sched._publish_gauges)
 
     en_pcts, on_toks = [], []
     for _ in range(5):
-        with obs.enabled(cfg=cfg, sals=sals, with_traffic=True):
+        with obs.enabled():
             spent["t"] = 0.0
             tok, wall, _ = drain(wrap=wrap)
             en_pcts.append(spent["t"] / wall * 100)

@@ -272,9 +272,14 @@ def main() -> None:
                          "path ending in .json gets the JSON snapshot "
                          "schema, anything else Prometheus text exposition")
     ap.add_argument("--trace-out", default="",
-                    help="write per-request lifecycle spans as Chrome-"
-                         "trace-event JSON (load in Perfetto / "
-                         "chrome://tracing)")
+                    help="write the span tracer as Chrome-trace-event JSON "
+                         "(load in Perfetto / chrome://tracing): one track "
+                         "per request's lifecycle, and the scheduler track "
+                         "of loop iterations and their parts (upkeep, "
+                         "admit, prefill_chunk, decode_dispatch, sample, "
+                         "device_wait, emit, on_step), on the host clock; "
+                         "the same scheduler spans also reach any "
+                         "jax.profiler trace as serve.<name>")
     ap.add_argument("--obs-snapshot-every", type=int, default=0,
                     help="re-export --metrics-out every N scheduler steps "
                          "while serving (0 = only at drain); implies "
@@ -330,13 +335,11 @@ def main() -> None:
                        gauge_history=args.gauge_history,
                        sals=sals or SALSConfig(enabled=False))
     # telemetry must be installed BEFORE the scheduler is built — it
-    # adopts the active registry/tracer/accountant in __init__
+    # adopts the active registry/tracer in __init__
     obs_handles = None
     if args.metrics_out or args.trace_out or args.obs_snapshot_every:
         from repro import obs
-        obs_handles = obs.enable(
-            gauge_history=args.gauge_history, cfg=cfg, sals=sals,
-            with_traffic=sals is not None and cfg.has_attention)
+        obs_handles = obs.enable(gauge_history=args.gauge_history)
     sched, _, _ = build(cfg, scfg, seed=args.seed, ckpt=args.ckpt,
                         n_groups=args.groups)
 
@@ -437,13 +440,6 @@ def main() -> None:
             print(f"[serve] trace -> {args.trace_out} "
                   f"({tracer.ended} spans, "
                   f"{'balanced' if tracer.balanced() else 'UNBALANCED'})")
-        traffic = obs_handles["traffic"]
-        if traffic is not None and traffic.reconciled:
-            rep = traffic.report()
-            meas = sum(rep["measured"].values())
-            print(f"[serve] traffic: {rep['reconciled']} steps reconciled "
-                  f"vs benchmarks/memory_access.py, {meas / 1e6:.1f} MB "
-                  f"measured, drifts={rep['drifts']}")
     for r in ok[:3]:
         print(f"  req {r.req_id}: prompt[{r.result.prompt_len}] -> "
               f"{r.result.tokens[:10]}...")

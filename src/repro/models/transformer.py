@@ -8,7 +8,13 @@ Segments.  The SALS layer mask (paper §5.1: layers 0, 1 and the last bypass
 sparsification) is always front/back-contiguous, so decode splits the stack
 into up to three scanned segments — ``full | sals | full`` — each with its
 own cache structure.  Step functions slice the stacked params per segment
-(static slices on the leading axis; XLA folds them).
+(static slices on the leading axis; compiled for a TPU, each is a copy).
+
+Named scopes.  ``decode_step`` and ``prefill_chunk`` name their parts, so
+a device profile can attribute every op: ``weights`` (the per-segment
+slices), ``seg<i>.<mode>`` (a segment's layer loop), ``cache_carry``
+(inside it, each layer's cache rows read out of and written back into the
+stack) and ``head`` (final norm and unembedding).
 
 Entry points
 ------------
@@ -75,6 +81,37 @@ def segment_plan(cfg: ModelConfig, sals: Optional[SALSConfig]
 
 def _slice_tree(tree, i0: int, i1: int):
     return jax.tree.map(lambda a: a[i0:i1], tree)
+
+
+def _scan_layers(step, x, xs, state):
+    """``lax.scan`` of ``step(x, xs_l, state_l) -> (x, state_l, y_l)`` over
+    a segment's layers; returns (x, state, ys).
+
+    ``state`` is the segment's layer-stacked cache (L, ...).  Each layer's
+    rows are read out and written back under the ``cache_carry`` scope, so
+    a profile tells moving the cache in and out of the loop from the
+    layer's own work.  The reads and writes are the ones ``lax.scan`` makes
+    for its ``xs`` and ``ys`` (a dynamic slice of the stacked input, a
+    dynamic update of a fresh stacked output), spelled out to carry that
+    name: the program is the same."""
+    with jax.named_scope("cache_carry"):
+        out = jax.tree.map(jnp.zeros_like, state)
+
+    def body(carry, xs_l):
+        x, i, out = carry
+        with jax.named_scope("cache_carry"):
+            st = jax.tree.map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False),
+                state)
+        x, st, y = step(x, xs_l, st)
+        with jax.named_scope("cache_carry"):
+            out = jax.tree.map(
+                lambda o, s: jax.lax.dynamic_update_index_in_dim(o, s, i, 0),
+                out, st)
+        return (x, i + 1, out), y
+
+    (x, _, out), ys = jax.lax.scan(body, (x, jnp.int32(0), out), xs)
+    return x, out, ys
 
 
 # ---------------------------------------------------------------------------
@@ -487,41 +524,44 @@ def prefill_chunk(params: dict, projectors: Optional[dict], cfg: ModelConfig,
     new_scratch: Dict[str, Any] = {}
 
     for si, (i0, i1, mode) in enumerate(segs):
-        bp_seg = _slice_tree(params["blocks"], i0, i1)
+        with jax.named_scope("weights"):
+            bp_seg = _slice_tree(params["blocks"], i0, i1)
+            u_seg = projectors["u"][i0:i1] if mode == "sals" else None
         seg_cache = cache[f"seg{si}"]
         if mode == "sals":
-            u_seg = projectors["u"][i0:i1]
             sc = scratch[f"seg{si}"]
 
-            def body_s(x, bp_u_cl_sc):
-                bp, u_l, cl, sk, sv = bp_u_cl_sc
+            def body_s(x, bp_u, cl_sc):
+                (bp, u_l), (cl, sk, sv) = bp_u, cl_sc
                 h = rmsnorm_apply(bp["attn_norm"], x, cfg.norm_eps)
                 a, k_pre, v, sk, sv = attn.attend_prefill_chunk(
                     bp["attn"], h, cfg, off, sk, sv)
                 cl = cl.append_chunk(cfg, sals, u_l, off, k_pre, v, len_v)
                 x, cl = _finish_block(bp, x, h, a, cl, None, cfg)
-                return x, (cl, sk, sv)
+                return x, (cl, sk, sv), None
 
-            x, (seg, sk, sv) = jax.lax.scan(
-                body_s, x, (bp_seg, u_seg, seg_cache, sc["k"], sc["v"]))
+            with jax.named_scope(f"seg{si}.{mode}"):
+                x, (seg, sk, sv), _ = _scan_layers(
+                    body_s, x, (bp_seg, u_seg), (seg_cache, sc["k"], sc["v"]))
             new_scratch[f"seg{si}"] = {"k": sk, "v": sv}
         else:
-            def body_f(x, bp_cl):
-                bp, cl = bp_cl
+            def body_f(x, bp, cl):
                 h = rmsnorm_apply(bp["attn_norm"], x, cfg.norm_eps)
                 a, _, _, kc, vc = attn.attend_prefill_chunk(
                     bp["attn"], h, cfg, off, cl["k"], cl["v"])
                 x, cl = _finish_block(bp, x, h, a, {"k": kc, "v": vc},
                                       None, cfg)
-                return x, cl
+                return x, cl, None
 
-            x, seg = jax.lax.scan(body_f, x, (bp_seg, seg_cache))
+            with jax.named_scope(f"seg{si}.{mode}"):
+                x, seg, _ = _scan_layers(body_f, x, bp_seg, seg_cache)
         new_cache[f"seg{si}"] = seg
 
-    x = rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
-    last_idx = jnp.clip(len_v - 1 - off, 0, c - 1)
-    last = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)
-    logits = unembed_apply(params["embed"], last, cfg)[:, 0]
+    with jax.named_scope("head"):
+        x = rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
+        last_idx = jnp.clip(len_v - 1 - off, 0, c - 1)
+        last = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)
+        logits = unembed_apply(params["embed"], last, cfg)[:, 0]
     return logits, new_cache, new_scratch
 
 
@@ -562,26 +602,26 @@ def decode_step(params: dict, projectors: Optional[dict], cache: dict,
     touched: Dict[str, Any] = {}
 
     for si, (i0, i1, mode) in enumerate(segs):
-        bp_seg = _slice_tree(params["blocks"], i0, i1)
+        with jax.named_scope("weights"):
+            bp_seg = _slice_tree(params["blocks"], i0, i1)
+            u_seg = projectors["u"][i0:i1] if mode == "sals" else None
         seg_cache = cache[f"seg{si}"]
         if cfg.family == "ssm":
-            def body_r(x, bp_st):
-                bp, st = bp_st
+            def body(x, bp, st):
                 h = rmsnorm_apply(bp["norm1"], x, cfg.norm_eps)
                 tm, wkv, tm_x = ssm_mod.rwkv_time_mix(bp["rwkv"], h, cfg, st)
                 x = x + tm
                 h2 = rmsnorm_apply(bp["norm2"], x, cfg.norm_eps)
                 cm, cm_x = ssm_mod.rwkv_channel_mix(bp["rwkv"], h2, st)
                 x = x + cm
-                return x, {"wkv": wkv, "tm_x": tm_x, "cm_x": cm_x}
-            x, new_seg = jax.lax.scan(body_r, x, (bp_seg, seg_cache))
+                return x, {"wkv": wkv, "tm_x": tm_x, "cm_x": cm_x}, None
+            xs = bp_seg
         elif mode == "sals":
-            u_seg = projectors["u"][i0:i1]
-
-            def body_sals(x, bp_u_cl):
-                bp, u_l, cl = bp_u_cl
+            def body(x, bp_u, cl):
+                bp, u_l = bp_u
                 h = rmsnorm_apply(bp["attn_norm"], x, cfg.norm_eps)
                 ssm_cl = cl.ssm if cfg.family == "hybrid" else None
+                t = None
                 if collect_selection:
                     a, cl, t = sals_decode_attend(bp["attn"], u_l, cl, h,
                                                   pos, cfg, sals,
@@ -589,17 +629,11 @@ def decode_step(params: dict, projectors: Optional[dict], cache: dict,
                 else:
                     a, cl = sals_decode_attend(bp["attn"], u_l, cl, h, pos,
                                                cfg, sals)
-                    t = jnp.zeros((), jnp.int32)   # unused ys placeholder
                 x, cl = _finish_block(bp, x, h, a, cl, ssm_cl, cfg)
-                return x, (cl, t)
-
-            x, (new_seg, seg_touch) = jax.lax.scan(
-                body_sals, x, (bp_seg, u_seg, seg_cache))
-            if collect_selection:
-                touched[f"seg{si}"] = seg_touch    # (ls, B, max_pages) bool
+                return x, cl, t
+            xs = (bp_seg, u_seg)
         else:
-            def body_full(x, bp_cl):
-                bp, cl = bp_cl
+            def body(x, bp, cl):
                 cl = dict(cl)
                 h = rmsnorm_apply(bp["attn_norm"], x, cfg.norm_eps)
                 ssm_cl = cl.pop("ssm") if cfg.family == "hybrid" else None
@@ -607,13 +641,17 @@ def decode_step(params: dict, projectors: Optional[dict], cache: dict,
                                                       cl["k"], cl["v"], pos)
                 cl = {"k": k_c, "v": v_c}
                 x, cl = _finish_block(bp, x, h, a, cl, ssm_cl, cfg)
-                return x, cl
-
-            x, new_seg = jax.lax.scan(body_full, x, (bp_seg, seg_cache))
+                return x, cl, None
+            xs = bp_seg
+        with jax.named_scope(f"seg{si}.{mode}"):
+            x, new_seg, seg_touch = _scan_layers(body, x, xs, seg_cache)
+        if collect_selection and mode == "sals":
+            touched[f"seg{si}"] = seg_touch        # (ls, B, max_pages) bool
         new_cache[f"seg{si}"] = new_seg
 
-    x = rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
-    logits = unembed_apply(params["embed"], x, cfg)[:, 0]
+    with jax.named_scope("head"):
+        x = rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
+        logits = unembed_apply(params["embed"], x, cfg)[:, 0]
     if collect_selection:
         return logits, new_cache, touched
     return logits, new_cache

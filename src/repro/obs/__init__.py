@@ -1,59 +1,51 @@
-"""Unified telemetry for the serving stack (ISSUE 10).
+"""Unified telemetry for the serving stack.
 
-Three instruments, one install contract (the ``serve/faults.py``
+Two instruments, one install contract (the ``serve/faults.py``
 nullable-singleton pattern — a disabled instrument costs one ``is None``
 check on the hot path):
 
 * :mod:`repro.obs.metrics`  — typed Counter/Gauge/Histogram registry,
   Prometheus-text + JSON snapshot exporters, core-reachable via
   ``core.pager._metrics_hook``.
-* :mod:`repro.obs.trace`    — per-request lifecycle span tracer with
-  Chrome-trace (Perfetto) export, plus :class:`RequestTimeline`, the one
-  TTFT / inter-token stamping path shared by benchmarks and live serving.
-* :mod:`repro.obs.traffic`  — measured-vs-modeled byte accountant that
-  enforces the §4.5 ledger at runtime (:class:`TrafficDriftError`).
+* :mod:`repro.obs.trace`    — span tracer with Chrome-trace (Perfetto)
+  export, plus :class:`RequestTimeline`, the one TTFT / inter-token
+  stamping path shared by benchmarks and live serving.  The scheduler's
+  own spans (one ``iteration`` per loop turn, split into upkeep,
+  admission, prefill chunks, decode dispatch, sampling, the device wait,
+  token emission and the client callback) are mirrored into
+  ``jax.profiler`` as ``serve.<name>`` annotations, so under a profiler
+  they sit on the same clock as the device's programs.
 
-``enable()`` wires all three for a scheduler run; ``enabled()`` is the
-context-manager form the tests use.
+``enable()`` wires both for a scheduler run; ``enabled()`` is the
+context-manager form the tests use.  A tracer alone is
+``obs.trace.install(SpanTracer())``.
 """
 from __future__ import annotations
 
 from contextlib import contextmanager
 
-from repro.obs import metrics, trace, traffic
+from repro.obs import metrics, trace
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import RequestTimeline, SpanTracer
-from repro.obs.traffic import TrafficAccountant, TrafficDriftError
 
 __all__ = [
     "MetricsRegistry", "RequestTimeline", "SpanTracer",
-    "TrafficAccountant", "TrafficDriftError",
-    "enable", "disable", "enabled", "metrics", "trace", "traffic",
+    "enable", "disable", "enabled", "metrics", "trace",
 ]
 
 
-def enable(gauge_history: int = 0, cfg=None, sals=None,
-           tol: float = 0.01, with_traffic: bool = False,
-           clock=None) -> dict:
-    """Install a fresh registry + tracer (+ traffic accountant when
-    ``with_traffic`` and a (cfg, sals) pair are given).  Returns the
-    handles; ``disable()`` reverses it."""
+def enable(gauge_history: int = 0, clock=None) -> dict:
+    """Install a fresh registry + tracer.  Returns the handles;
+    ``disable()`` reverses it."""
     reg = MetricsRegistry(max_series=gauge_history)
     kw = {"clock": clock} if clock is not None else {}
     tr = SpanTracer(max_events=gauge_history, **kw)
     metrics.install(reg)
     trace.install(tr)
-    acct = None
-    if with_traffic:
-        if cfg is None or sals is None:
-            raise ValueError("with_traffic=True needs cfg and sals")
-        acct = TrafficAccountant(cfg, sals, tol=tol, registry=reg)
-        traffic.install(acct)
-    return {"registry": reg, "tracer": tr, "traffic": acct}
+    return {"registry": reg, "tracer": tr}
 
 
 def disable():
-    traffic.uninstall()
     trace.uninstall()
     metrics.uninstall()
 

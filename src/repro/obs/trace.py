@@ -6,9 +6,22 @@ park/resume, tier fetch/spill transfers, speculative verify rounds,
 teardown) and exports them as Chrome-trace-event JSON (the ``X``
 complete-event form — load the file in Perfetto / chrome://tracing).
 
+Profiler mirror.  Every span on the ``scheduler`` track (the loop's
+``iteration`` and its children: ``upkeep``, ``admit``, ``prefill_chunk``,
+``decode_step``, ``decode_dispatch``, ``sample``, ``device_wait``,
+``emit``, ``on_step``; ``run_start``; tier transfers) is also opened as a
+``jax.profiler.TraceAnnotation`` named ``serve.<name>``, with the span's
+begin arguments as its metadata.  Under ``jax.profiler.trace`` those land
+in the same ``.xplane.pb`` as the device planes, stamped by the
+profiler's own clock, so a profile shows what the host loop was doing in
+every device idle gap.  With no profiler running an annotation costs a
+few microseconds; with no tracer installed nothing is created at all.
+Per-request lifecycle tracks are not mirrored: they overlap across
+requests and outlive any one step.
+
 Balance is an invariant, not a hope: ``begun``/``ended`` are cumulative
 counters that survive the ring cap, and :meth:`SpanTracer.end_track`
-closes every open span on a request's track so the PR 6 teardown/retry
+closes every open span on a track so the teardown/retry
 paths (fail, timeout, cancel, shed, evict-to-requeue) can never leak an
 open span.  Completed events ride a deque ring-capped by the same
 ``gauge_history`` policy as the scheduler's gauges (0 = unbounded).
@@ -31,8 +44,13 @@ from collections import deque
 from contextlib import contextmanager
 from typing import Dict, List, Optional
 
-__all__ = ["SpanTracer", "RequestTimeline", "active", "install",
-           "installed", "uninstall", "validate_chrome_trace"]
+import jax
+
+__all__ = ["SpanTracer", "RequestTimeline", "SCHEDULER_TRACK", "active",
+           "install", "installed", "uninstall", "validate_chrome_trace"]
+
+# the track whose spans are mirrored into the profiler as ``serve.<name>``
+SCHEDULER_TRACK = "scheduler"
 
 
 class SpanTracer:
@@ -59,8 +77,12 @@ class SpanTracer:
 
     def begin(self, name: str, track: str = "main", **args) -> int:
         sid = next(self._ids)
-        self._open[sid] = {"name": name, "track": str(track),
-                           "t0": self.clock(), "args": args or None}
+        sp = {"name": name, "track": str(track), "t0": self.clock(),
+              "args": args or None}
+        if sp["track"] == SCHEDULER_TRACK:
+            sp["ann"] = jax.profiler.TraceAnnotation(f"serve.{name}", **args)
+            sp["ann"].__enter__()
+        self._open[sid] = sp
         self.begun += 1
         return sid
 
@@ -71,6 +93,9 @@ class SpanTracer:
         sp = self._open.pop(sid, None)
         if sp is None:
             raise ValueError(f"span id {sid} is not open")
+        ann = sp.pop("ann", None)
+        if ann is not None:
+            ann.__exit__(None, None, None)
         t1 = self.clock()
         if args:
             sp["args"] = {**(sp["args"] or {}), **args}

@@ -632,17 +632,17 @@ class ServeEngine:
             key, logits / self.scfg.temperature, axis=-1).astype(jnp.int32)
 
     def sample_checked(self, logits: jnp.ndarray, key
-                       ) -> Tuple[jnp.ndarray, np.ndarray]:
+                       ) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """Sample plus a per-row validity verdict: ``ok[i]`` is False when
         row i's logits contain NaN/inf or the sampled id falls outside the
         vocab.  The scheduler fails ONLY the flagged rows (NanLogitsError,
         transient) — the other residents' tokens are taken as-is, which is
-        what confines a poisoned row to its own request."""
+        what confines a poisoned row to its own request.  Both stay on the
+        device: the caller reads them back together, once."""
         tok = self._sample(logits, key)
         finite = jnp.all(jnp.isfinite(logits), axis=-1)
         in_vocab = (tok >= 0) & (tok < self.cfg.vocab_size)
-        ok = np.asarray(finite & in_vocab)
-        return tok, ok
+        return tok, finite & in_vocab
 
     # -- continuous-batching primitives (used by RequestScheduler) -----------
 

@@ -167,6 +167,7 @@ DONE apart from FAILED / CANCELLED / TIMED_OUT.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import itertools
 import time
@@ -182,7 +183,6 @@ from repro.core.pager import (PagePool, PageTable, PagerInvariantError,
 from repro.core.tiering import HotTierThrash, TieredPagePool
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
-from repro.obs import traffic as obs_traffic
 from repro.obs.metrics import MetricsRegistry
 from repro.serve import faults
 from repro.serve.draft import NgramDrafter
@@ -192,6 +192,7 @@ from repro.serve.lifecycle import (NanLogitsError, QueueFull,
                                    RequestTimeout, transition)
 
 _req_ids = itertools.count()
+_NO_SPAN = contextlib.nullcontext()     # tracing off: one shared no-op
 
 
 @dataclasses.dataclass
@@ -397,7 +398,6 @@ class RequestScheduler:
         # to back the counter views, so disabled mode stays one-check cheap
         self._metrics_installed = obs_metrics.active() is not None
         self.tracer = obs_trace.active()        # None = spans disabled
-        self.traffic = obs_traffic.active()     # None = no byte accounting
         for view in (
                 # paged-pool observability (ISSUE 5 satellite)
                 "prefix_hits",       # admissions reusing pages
@@ -454,8 +454,6 @@ class RequestScheduler:
                                      n_reserved=1)
             if scfg.prefix_cache:
                 self.prefix_index = PrefixIndex(self.pool)
-            if self.traffic is not None:
-                self.traffic.bind_page_size(scfg.page_size)
         # live loop state, mirrored on self so audit_serving_state can see
         # it mid-run (tests also call it after run: drained == empty)
         self._slots: List[Optional[_Slot]] = []
@@ -771,7 +769,18 @@ class RequestScheduler:
         """
         if self.mode == "static":
             return self._run_static(on_batch)
-        return self._run_continuous(on_step)
+        try:
+            return self._run_continuous(on_step)
+        finally:
+            if self.tracer is not None:   # the last iteration, or what
+                self.tracer.end_track("scheduler")   # an exception left open
+
+    def _span(self, name: str, **args):
+        """A span on the scheduler track (mirrored into the profiler as
+        ``serve.<name>``); a shared no-op context when tracing is off."""
+        if self.tracer is None:
+            return _NO_SPAN
+        return self.tracer.span(name, "scheduler", **args)
 
     # ------------------------------------------------------------ continuous
 
@@ -787,13 +796,14 @@ class RequestScheduler:
         mp = eng.scfg.max_seq_len // ps if self.paged else 0
         chunks_per_sweep = max(1, eng.scfg.prefill_token_budget // chunk)
         audit_on = bool(eng.scfg.audit_every)
-        cache = eng.init_slot_cache()
+        with self._span("run_start"):      # the arena and the sampling key
+            cache = eng.init_slot_cache()
+            key = jax.random.PRNGKey(eng.scfg.seed)
         slots: List[Optional[_Slot]] = [None] * b
         self._slots = slots
         self._active = None        # in-flight admission; its slot reserved
         tokens = np.zeros((b,), np.int32)
         positions = np.zeros((b,), np.int32)
-        key = jax.random.PRNGKey(eng.scfg.seed)
         issued: List[Request] = []
         admit_seq = itertools.count()   # admission order (preempt tie-break)
         prio_on = (eng.scfg.priority_classes > 1
@@ -1038,7 +1048,7 @@ class RequestScheduler:
                     "tier_spill", "scheduler", page=victim)
                 mirror = eng.read_page_payload(cache, vslot)
                 pool.finish_spill(victim, mirror)
-                self._note_transfer("spill", mirror, sid)
+                self._note_transfer(mirror, sid)
                 hot_dirty[0] = True
                 slot = pool.take_slot()
             return slot
@@ -1066,7 +1076,7 @@ class RequestScheduler:
                 pool.give_slot(slot)
                 raise
             pool.finish_fetch(pid, slot)
-            self._note_transfer("fetch", payload, sid)
+            self._note_transfer(payload, sid)
             hot_dirty[0] = True
 
         def ensure_write_pin(i: int):
@@ -1144,9 +1154,11 @@ class RequestScheduler:
             nonlocal cache
             rounds = 0
             while True:
-                logits, cache, touched = eng._decode_sel(
-                    jnp.asarray(tokens), cache, jnp.asarray(positions))
-                tnp = np.asarray(touched)
+                with self._span("decode_dispatch"):
+                    logits, cache, touched = eng._decode_sel(
+                        jnp.asarray(tokens), cache, jnp.asarray(positions))
+                with self._span("device_wait"):
+                    tnp = np.asarray(touched)
                 touched_all: set = set()
                 new_prev: Dict[int, set] = {}
                 demand: List[tuple] = []
@@ -1294,7 +1306,7 @@ class RequestScheduler:
                         "tier_spill", "scheduler", page=pid, parked=True)
                     mirror = eng.read_page_payload(cache, vslot)
                     pool.finish_spill(pid, mirror)
-                    self._note_transfer("spill", mirror, sid)
+                    self._note_transfer(mirror, sid)
                     hot_dirty[0] = True
 
         def park_resident(i: int):
@@ -1538,46 +1550,54 @@ class RequestScheduler:
 
         while self.pending or self._active or self.parked \
                 or any(s is not None for s in slots):
-            sweep_deadlines_and_cancels()
-            self._refill_rate_credits()
-            if prio_on:
-                preempt_for_priority()
-            resume_ready_parked()
-            spill_parked_cold()
+            if self.tracer is not None:   # one span per loop turn
+                self.tracer.end_track("scheduler")
+                self.tracer.begin("iteration", "scheduler", step=self.steps,
+                                  n_live=sum(s is not None for s in slots))
+            with self._span("upkeep"):
+                sweep_deadlines_and_cancels()
+                self._refill_rate_credits()
+                if prio_on:
+                    preempt_for_priority()
+                resume_ready_parked()
+                spill_parked_cold()
 
             # ---- prefill sweep: ≤ budget tokens of chunk work; priority
             # classes first, DRR across tenants within a class ----------
             spent = 0
             while spent < chunks_per_sweep:
                 if self._active is None:
-                    free = next((i for i in range(b) if slots[i] is None),
-                                None)
-                    if free is None:
-                        break
-                    req = pop_eligible()
-                    if req is None:
-                        break
-                    if self.paged:
-                        try:
-                            self._active = try_reserve(req)
-                        except Exception as exc:   # torn reservation
-                            self._fail_or_retry(req, exc, issued)
-                            continue
-                        if self._active is None:  # stalled on pages, not
-                            # slots: back to the head, BEFORE any evicted
-                            # victims — after trying to reclaim pages from
-                            # a lower-priority parked victim (ISSUE 8)
-                            self.pending.appendleft(req)
-                            if reclaim_parked_pages(req):
-                                continue   # pages freed: retry right away
+                    with self._span("admit"):
+                        free = next(
+                            (i for i in range(b) if slots[i] is None), None)
+                        if free is None:
                             break
-                    else:
-                        self._active = _Admission(req, free,
-                                                  eng.start_prefill(
-                                                      req.prompt))
-                    req.attempts += 1
-                    transition(req, RequestState.PREFILLING)
-                    self._trace_phase(req, "prefill", attempt=req.attempts)
+                        req = pop_eligible()
+                        if req is None:
+                            break
+                        if self.paged:
+                            try:
+                                self._active = try_reserve(req)
+                            except Exception as exc:   # torn reservation
+                                self._fail_or_retry(req, exc, issued)
+                                continue
+                            if self._active is None:
+                                # stalled on pages, not slots: back to the
+                                # head, BEFORE any evicted victims — after
+                                # trying to reclaim pages from a
+                                # lower-priority parked victim
+                                self.pending.appendleft(req)
+                                if reclaim_parked_pages(req):
+                                    continue   # pages freed: retry at once
+                                break
+                        else:
+                            self._active = _Admission(req, free,
+                                                      eng.start_prefill(
+                                                          req.prompt))
+                        req.attempts += 1
+                        transition(req, RequestState.PREFILLING)
+                        self._trace_phase(req, "prefill",
+                                          attempt=req.attempts)
                 active = self._active
                 self.prefill_chunks.append(
                     (self.steps, active.req.req_id, active.task.next_chunk,
@@ -1602,66 +1622,72 @@ class RequestScheduler:
                     self.tracer.end(csid)
                 spent += 1
                 if active.task.done:
-                    i = active.slot
-                    try:
-                        if self.tiered:
-                            # residency first: the cold mirrors read the
-                            # task's dense cache, which the splice leaves
-                            # alive (only the ARENA is donated)
-                            hot_row = assign_residency(active)
-                            cache = eng.admit_tiered(
-                                cache, active.task.cache, i,
-                                active.ptab.pages, hot_row,
-                                active.shared_pages,
-                                active.task.prompt_len)
-                        elif self.paged:
-                            cache = eng.admit_paged(
-                                cache, active.task.cache, i,
-                                active.ptab.pages, active.shared_pages,
-                                active.task.prompt_len)
-                        else:
-                            cache = eng.admit(cache, active.task.cache, i)
-                    except Exception as exc:     # torn splice (pre-donate)
-                        teardown_admission(active)
+                    with self._span("admit"):
+                        i = active.slot
+                        try:
+                            if self.tiered:
+                                # residency first: the cold mirrors read the
+                                # task's dense cache, which the splice leaves
+                                # alive (only the ARENA is donated)
+                                hot_row = assign_residency(active)
+                                cache = eng.admit_tiered(
+                                    cache, active.task.cache, i,
+                                    active.ptab.pages, hot_row,
+                                    active.shared_pages,
+                                    active.task.prompt_len)
+                            elif self.paged:
+                                cache = eng.admit_paged(
+                                    cache, active.task.cache, i,
+                                    active.ptab.pages, active.shared_pages,
+                                    active.task.prompt_len)
+                            else:
+                                cache = eng.admit(cache, active.task.cache,
+                                                  i)
+                        except Exception as exc:  # torn splice (pre-donate)
+                            teardown_admission(active)
+                            self._active = None
+                            self._fail_or_retry(active.req, exc, issued)
+                            continue
+                        if self.paged:
+                            tables[i] = active.ptab
+                            host_table[i] = 0
+                            host_table[i, :active.ptab.n_pages] = \
+                                active.ptab.pages
+                            dirty[0] = True
+                            self._register_prefix(active)
+                        # ownership of ptab just moved to tables[i]: drop
+                        # the admission NOW so a teardown audit below
+                        # cannot count the same table twice (resident +
+                        # in-flight)
                         self._active = None
-                        self._fail_or_retry(active.req, exc, issued)
-                        continue
-                    if self.paged:
-                        tables[i] = active.ptab
-                        host_table[i] = 0
-                        host_table[i, :active.ptab.n_pages] = \
-                            active.ptab.pages
-                        dirty[0] = True
-                        self._register_prefix(active)
-                    # ownership of ptab just moved to tables[i]: drop the
-                    # admission NOW so a teardown audit below cannot count
-                    # the same table twice (resident + in-flight)
-                    self._active = None
-                    transition(active.req, RequestState.DECODING)
-                    self._trace_phase(active.req, "decode")
-                    key, sub = jax.random.split(key)
-                    tok_arr, ok = eng.sample_checked(active.task.logits, sub)
-                    if not ok[0]:
-                        # poisoned prompt logits: this request alone fails
-                        slots[i] = _Slot(active.req, out=[],
-                                         seq=next(admit_seq))
-                        fail_resident(i, NanLogitsError(
-                            f"req {active.req.req_id}: non-finite prefill "
-                            "logits"))
-                        continue
-                    tok0 = int(np.asarray(tok_arr)[0])
-                    slots[i] = _Slot(active.req, out=[tok0],
-                                     seq=next(admit_seq),
-                                     drafter=NgramDrafter(
-                                         list(active.req.prompt) + [tok0])
-                                     if spec_q else None)
-                    tokens[i] = tok0
-                    positions[i] = len(active.req.prompt)
-                    self.admissions.append((self.steps, i, active.req.req_id))
-                    if not emit_tokens(i, 1):
-                        continue
-                    if len(slots[i].out) >= active.req.max_new_tokens:
-                        finish(i)
+                        transition(active.req, RequestState.DECODING)
+                        self._trace_phase(active.req, "decode")
+                        key, sub = jax.random.split(key)
+                        tok_arr, ok = jax.device_get(eng.sample_checked(
+                            active.task.logits, sub))
+                        if not ok[0]:
+                            # poisoned prompt logits: this request alone
+                            # fails
+                            slots[i] = _Slot(active.req, out=[],
+                                             seq=next(admit_seq))
+                            fail_resident(i, NanLogitsError(
+                                f"req {active.req.req_id}: non-finite "
+                                "prefill logits"))
+                            continue
+                        tok0 = int(tok_arr[0])
+                        slots[i] = _Slot(active.req, out=[tok0],
+                                         seq=next(admit_seq),
+                                         drafter=NgramDrafter(
+                                             list(active.req.prompt) + [tok0])
+                                         if spec_q else None)
+                        tokens[i] = tok0
+                        positions[i] = len(active.req.prompt)
+                        self.admissions.append(
+                            (self.steps, i, active.req.req_id))
+                        if not emit_tokens(i, 1):
+                            continue
+                        if len(slots[i].out) >= active.req.max_new_tokens:
+                            finish(i)
 
             if not any(s is not None for s in slots):
                 if not (self.pending or self._active or self.parked):
@@ -1678,41 +1704,43 @@ class RequestScheduler:
             # ---- paged upkeep: map/COW every row's write page, then push
             # the host table to the device cache in one leaf swap ----------
             if self.paged:
-                for i in range(b):
-                    if slots[i] is not None:
-                        # speculative window: the verify step may commit up
-                        # to min(spec_window, remaining budget) tokens in
-                        # one shot — map every page that span can touch
-                        span = 1 if not spec_q else \
-                            min(spec_q, slots[i].req.max_new_tokens
-                                - len(slots[i].out))
-                        try:
-                            ensure_writable(i, span)
-                        except HotTierThrash as exc:
-                            shed_thrash(i, exc)    # load, not the request
-                        except Exception as exc:   # alloc/COW fault: only
-                            fail_resident(i, exc)  # row i pays
-                # ---- selection-driven prefetch (ISSUE 7): warm each
-                # row's PREVIOUS step's selected pages — the paper's
-                # stability insight says the next selection mostly repeats
-                # it (measured: benchmarks/overlap_score.py) ---------------
-                prefetched: set = set()
-                if self.tiered and eng.scfg.tier_prefetch:
+                with self._span("upkeep"):
                     for i in range(b):
-                        if slots[i] is None:
-                            continue
-                        try:
-                            for pid in sorted(prev_selected[i]):
-                                if pid in pool.cold:
-                                    fetch_page(pid, prev_selected[i])
-                                    prefetched.add(pid)
-                        except HotTierThrash:
-                            break   # hot tier saturated: best-effort only
-                        except faults.InjectedFault as exc:
-                            fail_resident(i, exc)   # prefetch blast radius
-                push_tables()
-                if not any(s is not None for s in slots):
-                    continue       # upkeep evicted/failed every resident
+                        if slots[i] is not None:
+                            # speculative window: the verify step may
+                            # commit up to min(spec_window, remaining
+                            # budget) tokens in one shot — map every page
+                            # that span can touch
+                            span = 1 if not spec_q else \
+                                min(spec_q, slots[i].req.max_new_tokens
+                                    - len(slots[i].out))
+                            try:
+                                ensure_writable(i, span)
+                            except HotTierThrash as exc:
+                                shed_thrash(i, exc)  # load, not the request
+                            except Exception as exc:  # alloc/COW fault:
+                                fail_resident(i, exc)  # only row i pays
+                    # ---- selection-driven prefetch: warm each row's
+                    # PREVIOUS step's selected pages — the paper's stability
+                    # insight says the next selection mostly repeats it
+                    # (measured: benchmarks/overlap_score.py) ---------------
+                    prefetched: set = set()
+                    if self.tiered and eng.scfg.tier_prefetch:
+                        for i in range(b):
+                            if slots[i] is None:
+                                continue
+                            try:
+                                for pid in sorted(prev_selected[i]):
+                                    if pid in pool.cold:
+                                        fetch_page(pid, prev_selected[i])
+                                        prefetched.add(pid)
+                            except HotTierThrash:
+                                break   # hot tier saturated: best-effort
+                            except faults.InjectedFault as exc:
+                                fail_resident(i, exc)   # prefetch radius
+                    push_tables()
+                    if not any(s is not None for s in slots):
+                        continue   # upkeep evicted/failed every resident
 
             # ---- one ragged decode step for the whole arena ---------------
             # (empty slots idle at position 0, harmlessly rewriting their
@@ -1737,16 +1765,11 @@ class RequestScheduler:
                     raise
                 continue
             fault_streak = 0
-            # ISSUE 10: step span + the live rows' context lengths, read
-            # BEFORE the step commits (the traffic accountant reconciles
-            # the §4.5 terms at exactly the positions the selection ran at)
             tr = self.tracer
             dsid = None if tr is None else tr.begin(
                 "verify_window" if spec_q else "decode_step", "scheduler",
                 step=self.steps,
                 n_live=sum(s is not None for s in slots))
-            live_pos = [int(positions[i]) for i in range(b)
-                        if slots[i] is not None]
             if spec_q:
                 # ---- speculative verify window (ISSUE 9): ONE latent
                 # selection + ONE windowed reconstruction serves the
@@ -1760,15 +1783,17 @@ class RequestScheduler:
                     if slots[i] is not None:
                         wt[i, 0] = tokens[i]
                         wt[i, 1:] = slots[i].drafter.propose(spec_q - 1)
-                win_logits, aux = eng._decode_window(
-                    jnp.asarray(wt), cache, jnp.asarray(positions))
+                with self._span("decode_dispatch"):
+                    win_logits, aux = eng._decode_window(
+                        jnp.asarray(wt), cache, jnp.asarray(positions))
                 live = [i for i in range(b) if slots[i] is not None]
                 pick = faults.maybe_pick("nan_logits", len(live))
                 if pick is not None:
                     # poison ONE live row's window logits — the finiteness
                     # verdict must confine the blast radius to that row
                     win_logits = win_logits.at[live[pick]].set(jnp.nan)
-                wl = np.asarray(win_logits)                   # (B, Q, V)
+                with self._span("device_wait"):
+                    wl = np.asarray(win_logits)               # (B, Q, V)
                 preds = wl.argmax(axis=-1).astype(np.int32)   # (B, Q)
                 finite = np.isfinite(wl).all(axis=(1, 2))
                 n_matched = np.cumprod(
@@ -1793,30 +1818,28 @@ class RequestScheduler:
                                            jnp.asarray(n_commit))
                 self.steps += 1
                 self.spec_rounds += 1
-                if self.traffic is not None:
-                    self.traffic.observe_decode(eng, cache, live_pos,
-                                                q_len=spec_q)
-                for i in range(b):
-                    if slots[i] is None:
-                        continue
-                    if not finite[i]:
-                        fail_resident(i, NanLogitsError(
-                            f"req {slots[i].req.req_id}: non-finite window "
-                            f"logits at step {self.steps}"))
-                        continue
-                    row = emitted[i]
-                    self.spec_proposed += spec_q - 1
-                    self.spec_accepted += min(int(n_matched[i]),
-                                              len(row) - 1)
-                    self.spec_committed += len(row)
-                    slots[i].out.extend(row)
-                    slots[i].drafter.extend(row)
-                    tokens[i] = row[-1]
-                    positions[i] += len(row)
-                    if not emit_tokens(i, len(row)):
-                        continue
-                    if len(slots[i].out) >= slots[i].req.max_new_tokens:
-                        finish(i)
+                with self._span("emit"):
+                    for i in range(b):
+                        if slots[i] is None:
+                            continue
+                        if not finite[i]:
+                            fail_resident(i, NanLogitsError(
+                                f"req {slots[i].req.req_id}: non-finite "
+                                f"window logits at step {self.steps}"))
+                            continue
+                        row = emitted[i]
+                        self.spec_proposed += spec_q - 1
+                        self.spec_accepted += min(int(n_matched[i]),
+                                                  len(row) - 1)
+                        self.spec_committed += len(row)
+                        slots[i].out.extend(row)
+                        slots[i].drafter.extend(row)
+                        tokens[i] = row[-1]
+                        positions[i] += len(row)
+                        if not emit_tokens(i, len(row)):
+                            continue
+                        if len(slots[i].out) >= slots[i].req.max_new_tokens:
+                            finish(i)
             else:
                 if self.tiered:
                     logits = tiered_decode(prefetched)
@@ -1825,39 +1848,40 @@ class RequestScheduler:
                             tr.end(dsid, aborted=True)
                         continue
                 else:
-                    logits, cache = eng._decode(
-                        jnp.asarray(tokens), cache, jnp.asarray(positions))
+                    with self._span("decode_dispatch"):
+                        logits, cache = eng._decode(
+                            jnp.asarray(tokens), cache,
+                            jnp.asarray(positions))
                 live = [i for i in range(b) if slots[i] is not None]
                 pick = faults.maybe_pick("nan_logits", len(live))
                 if pick is not None:
                     # poison ONE live row's logits — the blast radius the
                     # sample_checked verdict must confine to that row
                     logits = logits.at[live[pick]].set(jnp.nan)
-                key, sub = jax.random.split(key)
-                tok_arr, ok = eng.sample_checked(logits, sub)
-                new_toks = np.asarray(tok_arr)
+                with self._span("sample"):
+                    key, sub = jax.random.split(key)
+                    tok_arr, ok = eng.sample_checked(logits, sub)
+                # the step's one blocking readback: tokens and verdicts
+                with self._span("device_wait"):
+                    new_toks, ok = jax.device_get((tok_arr, ok))
                 self.steps += 1
-                if self.traffic is not None:
-                    # tiered fetch-and-rerun rounds re-stream the same
-                    # terms; the ledger (and so this reconciliation) is
-                    # per COMMITTED step — PCIe bytes are accounted at the
-                    # fetch/spill sites themselves
-                    self.traffic.observe_decode(eng, cache, live_pos)
-                for i in range(b):
-                    if slots[i] is None:
-                        continue
-                    if not ok[i]:
-                        fail_resident(i, NanLogitsError(
-                            f"req {slots[i].req.req_id}: non-finite logits "
-                            f"or out-of-vocab token at step {self.steps}"))
-                        continue
-                    slots[i].out.append(int(new_toks[i]))
-                    tokens[i] = new_toks[i]
-                    positions[i] += 1
-                    if not emit_tokens(i, 1):
-                        continue
-                    if len(slots[i].out) >= slots[i].req.max_new_tokens:
-                        finish(i)
+                with self._span("emit"):
+                    for i in range(b):
+                        if slots[i] is None:
+                            continue
+                        if not ok[i]:
+                            fail_resident(i, NanLogitsError(
+                                f"req {slots[i].req.req_id}: non-finite "
+                                f"logits or out-of-vocab token at step "
+                                f"{self.steps}"))
+                            continue
+                        slots[i].out.append(int(new_toks[i]))
+                        tokens[i] = new_toks[i]
+                        positions[i] += 1
+                        if not emit_tokens(i, 1):
+                            continue
+                        if len(slots[i].out) >= slots[i].req.max_new_tokens:
+                            finish(i)
             if dsid is not None:
                 tr.end(dsid)
             if self.paged:
@@ -1894,7 +1918,8 @@ class RequestScheduler:
                 self.audit_serving_state(
                     self.pool_gauges[-1] if self.pool_gauges else None)
             if on_step:
-                on_step(self, self.steps)
+                with self._span("on_step"):
+                    on_step(self, self.steps)
         self._publish_gauges()
         return issued
 
@@ -1907,17 +1932,11 @@ class RequestScheduler:
         return sum(int(a.nbytes)
                    for seg in mirror.values() for a in seg.values())
 
-    def _note_transfer(self, kind: str, mirror: dict,
-                       sid: Optional[int]) -> None:
-        """Account one host↔HBM page transfer: close its span with the
-        measured byte count and feed the traffic accountant."""
-        if self.tracer is None and self.traffic is None:
-            return
-        nbytes = self._mirror_nbytes(mirror)
+    def _note_transfer(self, mirror: dict, sid: Optional[int]) -> None:
+        """Close one host↔HBM page transfer's span with its measured byte
+        count."""
         if sid is not None:
-            self.tracer.end(sid, bytes=nbytes)
-        if self.traffic is not None:
-            self.traffic.observe_transfer(kind, 1, nbytes)
+            self.tracer.end(sid, bytes=self._mirror_nbytes(mirror))
 
     def _publish_gauges(self) -> None:
         """Refresh the registry's point-in-time gauges (occupancy, queue

@@ -937,11 +937,10 @@ def test_tiered_park_chaos_sweep(eng, eng_prio_tiered, model, seed):
 
 # ---------------------------------------------------------------------------
 # unified telemetry conservation (ISSUE 10): re-run the chaos episodes with
-# the FULL obs stack installed (registry + tracer + traffic accountant,
-# audit_every=1 on the engines) and assert the metrics themselves conserve:
-# submitted == Σ terminal-state counters, every span balanced with zero
-# dangling tracks at drain, exporters schema-valid, and the §4.5 byte
-# ledger reconciled on every committed step
+# the FULL obs stack installed (registry + tracer, audit_every=1 on the
+# engines) and assert the metrics themselves conserve: submitted == Σ
+# terminal-state counters, every span balanced with zero dangling tracks at
+# drain, exporters schema-valid
 # ---------------------------------------------------------------------------
 
 from repro import obs                                        # noqa: E402
@@ -961,10 +960,6 @@ def _obs_conservation_check(sched, handles):
     from repro.obs.metrics import validate_prometheus, validate_snapshot
     assert validate_snapshot(reg.snapshot()) == []
     assert validate_prometheus(reg.to_prometheus()) == []
-    acct = handles["traffic"]
-    if acct is not None:
-        assert acct.drifts == 0
-        assert acct.reconciled > 0
 
 
 @pytest.mark.parametrize("seed", [0, 1, 2])
@@ -977,7 +972,7 @@ def test_obs_chaos_conservation(eng, model, seed):
     ref = _reference(eng, model)
     reqs = _reqs(_workload(model))
     schedule = faults.FaultSchedule(seed=seed, rates=RATES)
-    with obs.enabled(cfg=cfg, sals=sals, with_traffic=True) as handles:
+    with obs.enabled() as handles:
         try:
             sched = _run(eng, reqs, schedule=schedule)
         except faults.InjectedFault:
@@ -993,14 +988,13 @@ def test_obs_chaos_conservation(eng, model, seed):
 
 @pytest.mark.parametrize("seed", [0, 1])
 def test_obs_tiered_chaos_conservation(eng, eng_tiered, model, seed):
-    """Tiered flavor: fetch/spill fault points fire mid-transfer while
-    the accountant reconciles every PCIe batch — spans for aborted
-    transfers must still close."""
+    """Tiered flavor: fetch/spill fault points fire mid-transfer — spans
+    for aborted transfers must still close."""
     cfg, params, sals, proj = model
     _reference(eng, model)
     reqs = _reqs(_workload(model))
     schedule = faults.FaultSchedule(seed=seed, rates=TIERED_RATES)
-    with obs.enabled(cfg=cfg, sals=sals, with_traffic=True) as handles:
+    with obs.enabled() as handles:
         try:
             sched = _run(eng_tiered, reqs, schedule=schedule)
         except faults.InjectedFault:
@@ -1019,7 +1013,7 @@ def test_obs_park_chaos_conservation(eng, eng_prio, model, seed):
     _park_reference(eng, model)
     reqs = _park_reqs(model, priorities=True)
     schedule = faults.FaultSchedule(seed=seed, rates=PARK_RATES)
-    with obs.enabled(cfg=cfg, sals=sals, with_traffic=True) as handles:
+    with obs.enabled() as handles:
         try:
             sched = _staged_park_run(eng_prio, reqs, schedule)
         except faults.InjectedFault:

@@ -1,5 +1,4 @@
-"""Unified telemetry (ISSUE 10): metrics registry, span tracer, traffic
-accountant.
+"""Unified telemetry: metrics registry and span tracer.
 
 Four contracts under test:
 
@@ -8,15 +7,18 @@ Four contracts under test:
       snapshot) emit schema-valid output;
   (b) spans balance — through every teardown/retry path, park/evict/fault
       episodes included — and the Chrome-trace export stays valid;
-  (c) the traffic accountant reconciles MEASURED decode-step bytes against
-      ``benchmarks/memory_access.py`` within 1% on the proxy config for the
-      dense, paged, tiered and speculative paths, and raises a typed
-      ``TrafficDriftError`` the moment the cache layout and the ledger
-      disagree;
-  (d) telemetry is invisible when disabled — the core hook stays None and
-      scheduler/engine behavior is unchanged.
+  (c) under ``jax.profiler`` the scheduler's spans land in the profile as
+      nested ``serve.*`` host events, one ``serve.decode_dispatch`` per
+      decode step, and the jitted steps keep their names while their ops
+      carry the model's named scopes (``weights``, ``seg*``,
+      ``cache_carry``, ``head``);
+  (d) telemetry is invisible when disabled — the core hook stays None,
+      no ``serve.*`` event reaches a profile, and scheduler/engine
+      behavior is unchanged.
 """
+import glob
 import json
+import re
 
 import jax
 import jax.numpy as jnp
@@ -31,7 +33,6 @@ from repro.models import transformer as tf
 from repro.obs.metrics import (MetricsRegistry, validate_prometheus,
                                validate_snapshot)
 from repro.obs.trace import RequestTimeline, SpanTracer, validate_chrome_trace
-from repro.obs.traffic import TrafficAccountant, TrafficDriftError
 from repro.serve import Request, RequestScheduler, RequestState, ServeEngine
 from repro.serve import faults
 
@@ -249,98 +250,176 @@ def test_timeline_attach_chains_two_arg_callback():
 
 
 # ---------------------------------------------------------------------------
-# (c) traffic accountant: measured == modeled on every serving path
+# (c) the profiler mirror and the named scopes
 # ---------------------------------------------------------------------------
 
-def _reconciled_run(eng, model, reqs, schedule=None, on_step=None):
-    cfg, params, sals, proj = model
-    with obs.enabled(cfg=cfg, sals=sals, with_traffic=True) as h:
-        sched = _drain(eng, reqs, schedule=schedule, on_step=on_step)
-        acct = h["traffic"]
-        assert acct.reconciled > 0, "accountant never saw a decode step"
-        assert acct.drifts == 0
-        rep = acct.report()
-        for term, meas in rep["measured"].items():
-            mod = rep["modeled"][term]
-            assert abs(meas - mod) <= 0.01 * max(meas, mod, 1.0), \
-                (term, meas, mod)
-        return sched, rep, h
+def _serve_events(profile_dir) -> list:
+    """The ``serve.*`` host events of the profile written under
+    ``profile_dir``: [(name, start_ns, end_ns, stats)], by start."""
+    from jax.profiler import ProfileData
+    out = []
+    for path in glob.glob(f"{profile_dir}/**/*.xplane.pb", recursive=True):
+        for plane in ProfileData.from_file(path).planes:
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith("serve."):
+                        out.append((ev.name, ev.start_ns,
+                                    ev.start_ns + ev.duration_ns,
+                                    dict(ev.stats)))
+    return sorted(out, key=lambda e: (e[1], -e[2]))
 
 
-def test_traffic_reconciles_dense(eng_dense, model):
-    reqs = [Request(p, max_new_tokens=4) for p in _prompts()]
-    _, rep, _ = _reconciled_run(eng_dense, model, reqs)
-    for term in ("score_bytes", "selected_bytes", "window_bytes", "u_bytes"):
-        assert rep["measured"][term] > 0
+@pytest.mark.parametrize("which", ["dense", "paged"])
+def test_scheduler_spans_reach_the_profile_nested(eng_dense, eng_paged,
+                                                  which, tmp_path):
+    eng = {"dense": eng_dense, "paged": eng_paged}[which]
+    reqs = [Request(p, max_new_tokens=5) for p in _prompts(51, n=3)]
+    tr = SpanTracer()
+    with obs.trace.installed(tr), jax.profiler.trace(str(tmp_path)):
+        sched = _drain(eng, reqs)
+    assert tr.balanced() and all(r.done for r in reqs)
+    evs = _serve_events(tmp_path)
+    names = [e[0] for e in evs]
+    for want in ("serve.run_start", "serve.iteration", "serve.upkeep",
+                 "serve.admit", "serve.prefill_chunk", "serve.decode_step",
+                 "serve.decode_dispatch", "serve.sample", "serve.device_wait",
+                 "serve.emit"):
+        assert want in names, want
+    # one dispatch, one sample and one readback per decode step
+    for one in ("decode_dispatch", "sample", "device_wait", "decode_step"):
+        assert names.count(f"serve.{one}") == sched.steps, one
+    iters = [e for e in evs if e[0] == "serve.iteration"]
+    assert {"step", "n_live"} <= set(iters[0][3])
+    # iterations tile the loop without overlapping; every other span but
+    # run_start lies inside one of them
+    for a, b in zip(iters, iters[1:]):
+        assert a[2] <= b[1]
+    for name, t0, t1, _ in evs:
+        if name in ("serve.iteration", "serve.run_start"):
+            continue
+        assert any(i[1] <= t0 and t1 <= i[2] for i in iters), name
+    # the device work of a step sits inside its decode_step span
+    steps = [e for e in evs if e[0] == "serve.decode_step"]
+    for name in ("serve.decode_dispatch", "serve.device_wait", "serve.emit"):
+        for e in (e for e in evs if e[0] == name):
+            assert any(d[1] <= e[1] and e[2] <= d[2] for d in steps), name
 
 
-def test_traffic_reconciles_paged(eng_paged, model):
-    reqs = [Request(p, max_new_tokens=4) for p in _prompts(43)]
-    sched, rep, _ = _reconciled_run(eng_paged, model, reqs)
-    assert sched.paged and rep["measured"]["score_bytes"] > 0
-
-
-def test_traffic_reconciles_tiered(eng_tiered, model):
-    """The PCIe terms: every fetch/spill's actual host-mirror nbytes must
-    equal pages x page_size x payload-bytes-per-token x SALS layers."""
-    rng = np.random.default_rng(44)
-    reqs = [Request(rng.integers(1, 128, size=30).astype(np.int32),
-                    max_new_tokens=8) for _ in range(5)]
-    sched, rep, _ = _reconciled_run(eng_tiered, model, reqs)
-    assert sched.tiered
-    assert sched.pool.spills > 0 or sched.pool.fetches > 0
-    if sched.pool.spills:
-        assert rep["measured"]["spill_bytes"] > 0
-    if sched.pool.fetches:
-        assert rep["measured"]["fetch_bytes"] > 0
-
-
-def test_traffic_reconciles_speculative(eng_spec, model):
-    """Verify windows reconcile the EXTRA in-flight window K/V term
-    against speculative_traffic_model."""
-    rng = np.random.default_rng(45)
+@pytest.mark.parametrize("which", ["tiered", "spec"])
+def test_tiered_and_speculative_steps_keep_their_spans_nested(
+        eng_tiered, eng_spec, which, tmp_path):
+    """The fetch-and-rerun loop dispatches and reads back its selection
+    once per round, then reads back the step's tokens; the verify window
+    dispatches and reads back once a round.  Every dispatch and readback
+    sits inside its step's span, and the step inside an iteration."""
+    eng = {"tiered": eng_tiered, "spec": eng_spec}[which]
+    rng = np.random.default_rng(53)
     base = rng.integers(1, 128, size=8).astype(np.int32)
     reqs = [Request(np.tile(base, 4)[:20 + 6 * i], max_new_tokens=8)
-            for i in range(2)]
-    sched, rep, _ = _reconciled_run(eng_spec, model, reqs)
-    assert sched.spec_rounds > 0
-    assert rep["measured"]["spec_window_bytes"] > 0
+            for i in range(3 if which == "tiered" else 2)]
+    tr = SpanTracer()
+    with obs.trace.installed(tr), jax.profiler.trace(str(tmp_path)):
+        sched = _drain(eng, reqs)
+    assert tr.balanced() and all(r.done for r in reqs)
+    evs = _serve_events(tmp_path)
+    step = "serve.verify_window" if which == "spec" else "serve.decode_step"
+    steps = [e for e in evs if e[0] == step]
+    assert len(steps) == sched.steps
+    iters = [e for e in evs if e[0] == "serve.iteration"]
+    for e in steps:
+        assert any(i[1] <= e[1] and e[2] <= i[2] for i in iters)
+    disp = [e for e in evs if e[0] == "serve.decode_dispatch"]
+    waits = [e for e in evs if e[0] == "serve.device_wait"]
+    assert len(disp) >= sched.steps
+    assert len(waits) == len(disp) + (sched.steps if which == "tiered" else 0)
+    for e in disp + waits:
+        assert any(d[1] <= e[1] and e[2] <= d[2] for d in steps)
 
 
-def test_traffic_drift_error_on_layout_tamper(eng_dense, model):
-    """Change the (believed) cache layout without updating the ledger and
-    the NEXT decode step raises a typed TrafficDriftError out of run() —
-    the ROADMAP ledger is an enforced invariant, not documentation."""
-    cfg, params, sals, proj = model
-    reqs = [Request(p, max_new_tokens=6) for p in _prompts(46, n=2)]
+@pytest.mark.parametrize("how", ["cancel", "deadline", "fault_raises"])
+def test_scheduler_track_balanced_on_every_exit(eng_dense, how):
+    """Whatever ends the run — a cancel, a missed deadline, or a fault
+    that escapes run() — no scheduler span (and so no profiler
+    annotation) is left open."""
+    reqs = [Request(p, max_new_tokens=12,
+                    timeout_steps=3 if how == "deadline" else None)
+            for p in _prompts(52, n=2)]
+    tr = SpanTracer()
 
-    def tamper(s, step):
-        acct = obs.traffic.active()
-        if step == 1 and acct.widths:
-            acct.widths["win_tokens"] += 5      # phantom window rows
+    def on_step(s, step):
+        if how == "cancel" and step == 2:
+            reqs[1].cancel()
 
-    with obs.enabled(cfg=cfg, sals=sals, with_traffic=True):
-        with pytest.raises(TrafficDriftError) as ei:
-            _drain(eng_dense, reqs, on_step=tamper)
-    assert ei.value.term == "window_bytes"
-    assert ei.value.measured > ei.value.modeled
+    with obs.trace.installed(tr):
+        if how == "fault_raises":
+            schedule = faults.FaultSchedule(at={"decode_step": range(50)})
+            with pytest.raises(faults.InjectedFault):
+                _drain(eng_dense, reqs, schedule=schedule)
+        else:
+            _drain(eng_dense, reqs, on_step=on_step)
+    assert obs.trace.SCHEDULER_TRACK not in tr.open_tracks()
+    assert not any("ann" in sp for sp in tr._open.values())
+    if how != "fault_raises":
+        assert tr.balanced() and all(r.finished for r in reqs)
 
 
-def test_traffic_accountant_empty_scope(model):
-    """A model whose every layer is a skip layer has an empty ledger —
-    the accountant observes nothing rather than erroring."""
-    cfg, params, sals, proj = model
-    import dataclasses
-    all_skip = dataclasses.replace(sals, skip_layers_front=2,
-                                   skip_layers_back=1)
-    acct = TrafficAccountant(cfg, all_skip)
+def _lowered_step(eng, step):
+    """(lowered program, function name) of one of the engine's jitted
+    steps, from shapes alone."""
+    b = eng.scfg.max_batch
+    if step == "decode":
+        cache = jax.eval_shape(eng._init_slots_impl)
+        return eng._decode.lower(jnp.zeros((b,), jnp.int32), cache,
+                                 jnp.zeros((b,), jnp.int32)), "_decode_impl"
+    cache, scratch = jax.eval_shape(eng._init_prefill_impl)
+    c = eng.scfg.prefill_chunk
+    return eng._prefill_chunk.lower(
+        jnp.zeros((1, c), jnp.int32), cache, scratch, jnp.int32(0),
+        jnp.ones((1,), jnp.int32)), "_prefill_chunk_impl"
 
-    class _FakeEngine:
-        def _latent_segs(self, cache):
-            return {}
 
-    acct.observe_decode(_FakeEngine(), {}, [10, 20])
-    assert acct.reconciled == 0 and acct.drifts == 0
+@pytest.mark.parametrize("step", ["decode", "prefill_chunk"])
+def test_jitted_steps_keep_names_and_carry_scopes(eng_dense, step):
+    """The benchmark finds the programs by name and attributes their ops
+    by scope: both must survive.  (What a chip's compiler keeps of them
+    is checked in tests/test_tpu_compile.py.)"""
+    lowered, fn = _lowered_step(eng_dense, step)
+    assert lowered.as_text().startswith(f"module @jit_{fn}")
+    locs = set(re.findall(r'loc\("([^"]+)"',
+                          lowered.as_text(debug_info=True)))
+    for scope in ("weights", "seg0.full", "seg1.sals", "seg2.full",
+                  "cache_carry", "head"):
+        assert any(f"{scope}/" in n for n in locs), scope
+    assert f"jit({fn})/weights/slice" in locs
+    for op in ("dynamic_slice", "dynamic_update_slice"):
+        assert f"cache_carry/{op}" in locs, op
+
+
+def test_scan_layers_matches_lax_scan():
+    """The named cache carry is lax.scan's own xs/ys plumbing."""
+    x0 = jnp.arange(6.0).reshape(2, 3)
+    w = jnp.linspace(0.5, 1.5, 4 * 3 * 3).reshape(4, 3, 3)
+    state = {"k": jnp.arange(4 * 2 * 5.0).reshape(4, 2, 5),
+             "n": jnp.arange(4, dtype=jnp.int32)}
+
+    def step(x, w_l, st):
+        x = jnp.tanh(x @ w_l)
+        st = {"k": st["k"].at[:, 0].add(x[:, 0]), "n": st["n"] + 1}
+        return x, st, x.sum()
+
+    def ref_body(x, w_st):
+        w_l, st = w_st
+        x, st, y = step(x, w_l, st)
+        return x, (st, y)
+
+    x_r, (st_r, y_r) = jax.lax.scan(ref_body, x0, (w, state))
+    x_s, st_s, y_s = jax.jit(tf._scan_layers, static_argnums=0)(
+        step, x0, w, state)
+    np.testing.assert_array_equal(x_s, x_r)
+    np.testing.assert_array_equal(y_s, y_r)
+    for k in state:
+        np.testing.assert_array_equal(st_s[k], st_r[k])
+        assert st_s[k].dtype == state[k].dtype
 
 
 # ---------------------------------------------------------------------------
@@ -351,7 +430,7 @@ def test_counter_views_are_registry_backed(eng_dense, model):
     """Legacy public fields (prefix_hits, failures, ...) stay readable /
     writable but the registry is the single store."""
     cfg, params, sals, proj = model
-    with obs.enabled(cfg=cfg, sals=sals) as h:
+    with obs.enabled() as h:
         sched = RequestScheduler(eng_dense, mode="continuous")
         assert sched.metrics is h["registry"]
         sched.prefix_hits += 3
@@ -365,7 +444,7 @@ def test_metrics_conservation_and_terminal_counters(eng_dense, model):
     in the public views AND the registry series they proxy."""
     rng = np.random.default_rng(47)
     cfg, params, sals, proj = model
-    with obs.enabled(cfg=cfg, sals=sals) as h:
+    with obs.enabled() as h:
         reqs = [Request(rng.integers(1, 128, size=12).astype(np.int32),
                         max_new_tokens=6) for _ in range(3)]
         reqs.append(Request(rng.integers(1, 128, size=12).astype(np.int32),
@@ -426,7 +505,7 @@ def test_spans_balance_park_evict_fault_episode(model):
     eng_p = _engine(model, page_size=16, audit_every=1, max_batch=2,
                     priority_classes=2, preempt_policy="park")
     prompts = _prompts(48, n=5)
-    with obs.enabled(cfg=cfg, sals=sals, with_traffic=True) as h:
+    with obs.enabled() as h:
         sched = RequestScheduler(eng_p, mode="continuous")
         lo = [Request(p, max_new_tokens=8, priority=0) for p in prompts[:2]]
         hi = [Request(p, max_new_tokens=4, priority=1) for p in prompts[2:]]
@@ -452,11 +531,10 @@ def test_spans_balance_park_evict_fault_episode(model):
         for want in ("queue_wait", "prefill", "prefill_chunk", "decode",
                      "decode_step", "parked", "teardown"):
             assert want in names, f"missing lifecycle span {want!r}"
-        assert h["traffic"].drifts == 0
     # evict flavor of the same episode
     eng_e = _engine(model, page_size=16, audit_every=1, max_batch=2,
                     priority_classes=2, preempt_policy="evict")
-    with obs.enabled(cfg=cfg, sals=sals) as h:
+    with obs.enabled() as h:
         sched = RequestScheduler(eng_e, mode="continuous")
         lo = [Request(p, max_new_tokens=8, priority=0) for p in prompts[:2]]
         hi = [Request(p, max_new_tokens=4, priority=1) for p in prompts[2:]]
@@ -474,12 +552,12 @@ def test_spans_balance_park_evict_fault_episode(model):
         assert validate_chrome_trace(h["tracer"].chrome_trace()) == []
 
 
-def test_disabled_mode_is_invisible(eng_dense, model):
+def test_disabled_mode_is_invisible(eng_dense, model, tmp_path):
     """(d) With nothing installed the scheduler runs exactly as before:
-    no tracer, no traffic, public views still count, same tokens as an
-    enabled run (telemetry must never perturb decoding)."""
+    no tracer, public views still count, no ``serve.*`` event reaches a
+    profile, same tokens as an enabled run (telemetry must never perturb
+    decoding)."""
     from repro.core import pager
-    cfg, params, sals, proj = model
     prompts = _prompts(49, n=2)
 
     def run():
@@ -488,10 +566,12 @@ def test_disabled_mode_is_invisible(eng_dense, model):
         return sched, [r.result.tokens.copy() for r in reqs]
 
     assert obs.metrics.active() is None and pager._metrics_hook is None
-    sched_off, toks_off = run()
-    assert sched_off.tracer is None and sched_off.traffic is None
+    with jax.profiler.trace(str(tmp_path)):
+        sched_off, toks_off = run()
+    assert _serve_events(tmp_path) == []
+    assert sched_off.tracer is None
     assert sched_off.done == 2                  # local registry backs views
-    with obs.enabled(cfg=cfg, sals=sals, with_traffic=True):
+    with obs.enabled():
         sched_on, toks_on = run()
     for a, b in zip(toks_off, toks_on):
         np.testing.assert_array_equal(a, b)
@@ -504,7 +584,7 @@ def test_engine_decode_throughput_on_tracer(eng_dense, model):
     cfg, params, sals, proj = model
     tput = eng_dense.decode_throughput(2, 16, n_steps=2)   # disabled path
     assert tput > 0
-    with obs.enabled(cfg=cfg, sals=sals) as h:
+    with obs.enabled() as h:
         tput = eng_dense.decode_throughput(2, 16, n_steps=2)
         assert tput > 0
         g = h["registry"].gauge("engine_decode_tokens_per_s",
@@ -519,7 +599,7 @@ def test_launcher_style_export_roundtrip(eng_dense, model, tmp_path):
     """The --metrics-out/--trace-out shapes: both files written at drain
     validate, and the JSON snapshot round-trips."""
     cfg, params, sals, proj = model
-    with obs.enabled(cfg=cfg, sals=sals, with_traffic=True) as h:
+    with obs.enabled() as h:
         reqs = [Request(p, max_new_tokens=4) for p in _prompts(50, n=2)]
         _drain(eng_dense, reqs)
         prom = tmp_path / "metrics.prom"

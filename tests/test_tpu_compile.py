@@ -146,3 +146,53 @@ def test_flash_attention_compiles_for_v5e(one_chip, for_tpu):
     hlo = _compile(lambda q, k, v: fa.flash_attention_pallas(q, k, v),
                    x, x, x)
     assert "flash_attention" in hlo
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill_chunk"])
+def test_step_scopes_survive_the_v5e_compiler(one_chip, for_tpu, step):
+    """The chip's compiler keeps the model's named scopes on the ops a
+    profile attributes by them: the per-segment weight slices and the
+    layer loop's cache carry (a small dense SALS model, XLA attention)."""
+    import re
+
+    from repro.config import ModelConfig, SALSConfig, ServeConfig
+    from repro.models import transformer as tf
+    from repro.serve import ServeEngine
+
+    cfg = ModelConfig(name="tiny", family="dense", n_layers=4, d_model=256,
+                      n_heads=4, n_kv_heads=2, head_dim=64, d_ff=512,
+                      vocab_size=512, dtype="bfloat16")
+    sals = SALSConfig(rank_ratio=0.5, score_ratio=0.5, n_critical=16,
+                      n_sink=2, n_recent=8, v_bits=8, v_group=32,
+                      skip_layers_front=1, skip_layers_back=1)
+    scfg = ServeConfig(max_seq_len=256, max_batch=2, sals=sals,
+                       prefill_chunk=32)
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: _spec(one_chip, a.shape, a.dtype), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: tf.init_params(jax.random.PRNGKey(0), cfg)))
+    kvd = cfg.n_kv_heads * cfg.head_dim
+    proj = {"u": _spec(one_chip, (cfg.n_layers, kvd, sals.rank(kvd)),
+                       jnp.bfloat16)}
+    eng = ServeEngine(params, proj, cfg, scfg)
+    i32 = jnp.int32
+    if step == "decode":
+        lowered = eng._decode.lower(
+            _spec(one_chip, (2,), i32),
+            on_chip(jax.eval_shape(eng._init_slots_impl)),
+            _spec(one_chip, (2,), i32))
+    else:
+        cache, scratch = on_chip(jax.eval_shape(eng._init_prefill_impl))
+        lowered = eng._prefill_chunk.lower(
+            _spec(one_chip, (1, 32), i32), cache, scratch,
+            _spec(one_chip, (), i32), _spec(one_chip, (1,), i32))
+    hlo = lowered.compile().as_text()
+    named = re.findall(r'%([\w.-]+) = .*?op_name="([^"]+)"', hlo)
+    carry = {op.split(".")[0] for op, name in named
+             if "/cache_carry/" in name}
+    assert any("dynamic-update-slice" in op for op in carry), carry
+    assert any("dynamic-slice" in op for op in carry), carry
+    assert any("/weights/slice" in name for _, name in named)
