@@ -330,8 +330,10 @@ class ServeConfig:
     Sizing rule: page-table overhead is ``4 / page_size`` bytes per token
     (one int32 table entry per page) — < 2% of the latent payload for any
     ``page_size`` ≥ 1 at the paper geometry (r·b_lat ≈ 2 KiB/token), so
-    pick ``page_size`` by DMA burst width (reconstruct gathers one page
-    per DMA; 16–64 is the sweet spot) and prefix-sharing granularity
+    pick ``page_size`` by the score kernel's DMA burst (it streams one
+    page per DMA; reconstruct copies an 8-row tile per selected token
+    whatever the page size, and its scale chunk where a page holds 128
+    rows or more) and prefix-sharing granularity
     (smaller pages share shorter common prefixes), NOT by metadata cost.
     ``n_pages`` (0 = auto: ``max_batch · max_seq_len / page_size``, the
     dense-equivalent capacity) sizes the pool; admission reserves a

@@ -464,8 +464,13 @@ class LatentKVCache:
                                            k_lat[..., :r_star])
         vq = qz.quantize(v_flat, sals.v_bits, sals.v_group)
         out["v_q"] = upd(self.v_q, vq["q"])
-        out["v_scale"] = upd(self.v_scale, vq["scale"])
-        out["v_zero"] = upd(self.v_zero, vq["zero"])
+        # the group scales are G = kvd / v_group lanes wide and XLA keeps
+        # them with the sequence axis in lanes; a masked write leaves them
+        # so, where a row scatter moves the whole array to rows and back
+        put = upd if self.paged else lambda arr, val: _put_rows(arr, val,
+                                                                  wpos)
+        out["v_scale"] = put(self.v_scale, vq["scale"])
+        out["v_zero"] = put(self.v_zero, vq["zero"])
         if self.lengths is not None:
             adv = pos_v + 1 if keep is None else \
                 jnp.where(keep, pos_v + 1, 0)
@@ -707,6 +712,14 @@ def cache_bytes_per_token(cfg: ModelConfig, sals: SALSConfig) -> float:
 def _row_positions(pos, batch: int) -> jnp.ndarray:
     """Normalize a scalar-or-(B,) decode position to (B,) int32."""
     return jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (batch,))
+
+
+def _put_rows(arr, val, pos_v):
+    """:func:`_upd_rows` as a masked write over the whole (B, S, ·) array:
+    the same values, with no scatter; a position out of range writes
+    nothing."""
+    hit = jnp.arange(arr.shape[1])[None, :, None] == pos_v[:, None, None]
+    return jnp.where(hit, val[:, None].astype(arr.dtype), arr)
 
 
 def _upd_rows(arr, val, pos_v):

@@ -43,7 +43,9 @@ Sizing rule (also documented on ``ServeConfig``): page-table overhead is
 (r=1024 bf16 latents ≈ 2 KiB/token) even page_size=16 costs < 0.02%.
 Small pages waste less tail (half a page per sequence on average) and
 share prefixes at finer granularity; the floor is DMA efficiency of the
-reconstruct pass (one page = one DMA burst).  ``page_size`` must divide
+score pass (one page = one DMA burst; reconstruct copies an 8-row tile
+per selected token whatever the page size, and the token's scale chunk
+where a page holds 128 rows or more).  ``page_size`` must divide
 ``max_seq_len`` and be a multiple of ``prefill_chunk`` (prefix-resume
 boundaries are chunk-aligned).
 """

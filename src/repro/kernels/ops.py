@@ -335,9 +335,10 @@ def sparse_recon_attention(q, k_lat, k_scale, v_q, v_scale, v_zero, u,
     positions (grouped layout: idx is slab-local, position is
     ``pos_base[b] + idx[b, n]``).  ``page_table``/``page_size``: paged
     layout — cache operands are page pools, ``idx`` stays logical, the
-    Pallas path DMAs whole pages through the table (sorted idx → one DMA
-    per page touched).  See ref.sparse_recon_attention_fused_ref for the
-    full contract."""
+    Pallas path copies each selected token's 8-row tile (and, with pages
+    of 128 rows or more, its scale chunk) from its page through the
+    table.  See ref.sparse_recon_attention_fused_ref for the full
+    contract."""
     backend = backend or default_backend()
     if page_table is not None:
         if backend == "pallas":

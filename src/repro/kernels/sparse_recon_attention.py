@@ -2,33 +2,56 @@
 (SALS stages 3-4: gather → dequant → reconstruct → RoPE → online-softmax).
 
 The top-k indices are the ONLY thing that travels from selection to this
-kernel.  The (B, N_c) index array arrives as a scalar-prefetch operand
-(``pltpu.PrefetchScalarGridSpec``); every cache operand's ``index_map``
-dereferences it, so the pipeline DMAs the block holding each selected
-token straight from the raw cache arrays in HBM into VMEM — the TPU
-analogue of the paper's fused Triton gather:
+kernel.  The selection is padded to ``n_blocks × T`` entries (pad entries
+invalid) and the grid is ``(B, n_blocks)``: each grid step handles a
+block of T selected tokens.  T follows the shapes the kernel sees — at
+most 128 (one MXU pass), fewer where N_c is small or the double-buffered
+gather would not fit its VMEM share — and is balanced over the blocks so
+the pad stays under 8 entries a block.
 
-    dense  (B, S, ·) arrays    -> the aligned 8-row tile holding the token
-    paged  (n_pages, ps, ·)    -> the whole page holding the token
+Gather.  The cache arrays stay in HBM (``memory_space=ANY``) and the
+kernel copies what each token needs with manual DMAs.  A TPU copy's
+second-minor extent must be a multiple of 8, so a single row cannot be
+copied: a token's latents and value codes come in the aligned tile of 8
+rows holding its row,
 
-(a TPU block's second-minor dim must be a multiple of 8, so a single row
-cannot be a block).  The selection is sorted ascending, so consecutive
-grid steps that land in the same tile or page keep the block index and
-Pallas skips the re-DMA.  The kernel picks the token's row from the block
-with a masked sublane sum.
+    dense  (B, S, ·) arrays    -> rows [tok // 8 · 8, +8) of row b
+    paged  (n_pages, ps, ·)    -> the same rows of the physical page,
+                                  resolved through the page table
 
-Per selected token, entirely in VMEM/vregs, in a flat (·, kvd) lane
-layout (head h owns lanes [h·dh, (h+1)·dh); no reshape crosses lanes
-into sublanes, which Mosaic refuses):
+and its value group scales and zero points, G = kvd / v_group wide, in
+the aligned chunk of 128 positions holding it, from the arrays viewed
+sequence axis last, (B, G, S) or (n_pages, G, ps).  XLA keeps arrays
+this narrow with the sequence axis in lanes, so the view costs no copy.
+Mosaic copies no lane slice narrower than 128, so where 128 does not
+divide the sequence axis (pages under 128 rows) the selected tokens'
+scales are gathered outside instead and arrive as (G, T) blocks.
+The copies land in a ``(2, T, ·, ·)`` VMEM buffer per array; those of
+the next block are started before this block is computed (double
+buffering across the whole grid, rows included), and one wait per array
+and slot covers all T copies.  Each token's row is then picked from its
+tile with a masked sublane sum, and its scales from their chunk with a
+masked lane sum (exact: one term is the entry, the rest are zeros); the
+int8 latent scale, where there is one, rides in SMEM.
 
-    1. dequantize the latent (int8 × per-token scale, read from SMEM),
-    2. reconstruct  k = k̃ · U_rᵀ  (one (1,r)·(r,kvd) matvec on the MXU),
-    3. RoPE at the token's *original* position with lane rolls,
-    4. GQA logits: the RoPE'd query tile (q_len·group, kvd) times k, summed
-       per head's lane span -> (q_len·group, n_kv),
-    5. dequantize the value codes (int4 codes unpack to [even | odd]
-       channel order; the output is put back in order outside) and
-       online-softmax accumulate (m, l, acc) across the N_c grid steps.
+Per block, entirely in VMEM/vregs:
+
+    1. reconstruct Kᵀ = U_r · latᵀ, one (kvd, r)·(r, T) matmul at
+       ``Precision.HIGHEST`` (U_r is read into VMEM once per call);
+    2. RoPE each column at the token's *original* position
+       (``pos_base + idx``), from one (dh/2, T) angle table;
+    3. logits of every (query, kv head) row against the T tokens with one
+       matmul: the kernel spreads the queries block-diagonal once a row,
+       row ``h·Q·group + j`` holding query j of kv head h in head h's
+       lanes and zeros elsewhere, so one (n_kv·Q·group, kvd)·(kvd, T)
+       product gives every head's logits; softcap, then the valid and
+       windowed ``n_recent`` masks per column;
+    4. dequantize the T value rows (each group's scale and zero point
+       reach its lanes through one matmul with a 0/1 matrix; int4 codes
+       unpack to [even | odd] channel order) and online-softmax
+       accumulate (m, l, acc) with one (rows, T)·(T, kvd) matmul; after
+       the last block each row keeps its own head's channels, in channel
+       order, through one matmul with a 0/1 (kvd, dh) matrix.
 
 No gathered, dequantized, or reconstructed buffer ever touches HBM.
 Returns flash-style partials (m, l, o) for LSE-merging with the
@@ -64,147 +87,234 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import interpret_mode
 from repro.kernels.ref import NEG_INF, _rope
 
-_TILE = 8          # rows of the dense gather block / of a row pick
+_TILE = 8               # rows of the tile copied for a token's latents/codes
+_LANES = 128            # lanes of the chunk copied for a token's value scales
+_BLOCK = 128            # selected tokens a grid step at most: one MXU pass
+_GATHER_VMEM = 16 << 20  # VMEM the double-buffered gather may hold
+_VMEM_LIMIT = 48 << 20  # the kernel's scoped VMEM (v5e has 128 MiB)
+_NEVER = jnp.iinfo(jnp.int32).max   # visible position of an invalid entry
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def _pick_row(ref, row, dtype) -> jnp.ndarray:
-    """Row ``row`` of the (1, T, w) block ``ref`` as a (1, w) ``dtype``
-    value: an aligned 8-row slice, then a masked sublane sum (exact —
-    one term is the row, the rest are zeros)."""
-    t = ref.shape[1]
-    if t > _TILE:
-        start = pl.multiple_of((row // _TILE) * _TILE, _TILE)
-        x = ref[0, pl.ds(start, _TILE), :]
-        row = row - start
-    else:
-        x = ref[0]
-    x = x.astype(dtype)
-    hit = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0) == row
-    return jnp.sum(jnp.where(hit, x, jnp.zeros_like(x)), axis=0,
-                   keepdims=True)
+def _block_size(nc: int, slabs) -> Tuple[int, int]:
+    """(T, n_blocks) for N_c selected tokens: as few blocks of at most
+    _BLOCK tokens as the gather's two slots per cache array fit in
+    _GATHER_VMEM, T a multiple of 8 that balances them.  ``slabs`` holds
+    the (rows, lanes, dtype) of one token's copy of each array; VMEM pads
+    it to the dtype's packed sublanes and to 128 lanes."""
+    per_token = 0
+    for rows, lanes, dtype in slabs:
+        size, pack = jnp.dtype(dtype).itemsize, 32 // jnp.dtype(dtype).itemsize
+        per_token += (pl.cdiv(rows, pack) * pack * pl.cdiv(lanes, 128) * 128
+                      * size)
+    t_max = _BLOCK
+    while t_max > _TILE and 2 * t_max * per_token > _GATHER_VMEM:
+        t_max //= 2
+    nb = max(1, pl.cdiv(nc, t_max))
+    return _TILE * pl.cdiv(pl.cdiv(nc, nb), _TILE), nb
 
 
-def _spread(x: jnp.ndarray, key: jnp.ndarray, n: int) -> jnp.ndarray:
-    """(rows, n) -> (rows, kvd): lane i takes column ``key[i]`` of x."""
-    out = jnp.zeros((x.shape[0], key.shape[1]), jnp.float32)
-    for c in range(n):
-        out = jnp.where(key == c, x[:, c:c + 1], out)
-    return out
+def _as_f32(x: jnp.ndarray) -> jnp.ndarray:
+    """f32 copy of a tile, through int32 for integer codes."""
+    if jnp.issubdtype(x.dtype, jnp.integer):
+        x = x.astype(jnp.int32)
+    return x.astype(jnp.float32)
 
 
-def _head_sums(prod: jnp.ndarray, n_kv: int, dh: int) -> jnp.ndarray:
-    """(rows, kvd) -> (rows, n_kv): sum over each head's lane span."""
-    col = jax.lax.broadcasted_iota(jnp.int32, (prod.shape[0], n_kv), 1)
-    out = jnp.zeros((prod.shape[0], n_kv), jnp.float32)
-    for h in range(n_kv):
-        s = jnp.sum(prod[:, h * dh:(h + 1) * dh], axis=1, keepdims=True)
-        out = jnp.where(col == h, s, out)
-    return out
+def _pick(x: jnp.ndarray, at, axis: int) -> jnp.ndarray:
+    """Index ``at`` of ``axis`` of the f32 tile x, kept as a length-1 axis:
+    a masked sum (exact — one term is the entry, the rest are zeros)."""
+    hit = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis) == at
+    return jnp.sum(jnp.where(hit, x, 0.0), axis=axis, keepdims=True)
 
 
-def _rope_flat(k: jnp.ndarray, pos, freq: jnp.ndarray, dh: int
-               ) -> jnp.ndarray:
-    """Half-rotation RoPE of a (1, kvd) key at ``pos``; ``freq`` (1, kvd)
-    holds each lane's rotary frequency."""
+def _rope_cols(kt: jnp.ndarray, pos: jnp.ndarray, freq: jnp.ndarray,
+               n_kv: int, dh: int) -> jnp.ndarray:
+    """Half-rotation RoPE of Kᵀ (kvd, T), column t at position
+    ``pos[0, t]``; ``freq`` (dh/2, 1) holds the rotary frequencies."""
     half = dh // 2
-    kvd = k.shape[1]
-    ang = pos.astype(jnp.float32) * freq
-    lane = jax.lax.broadcasted_iota(jnp.int32, k.shape, 1) % dh
-    ahead = pltpu.roll(k, kvd - half, 1)          # k[i + half]
-    behind = pltpu.roll(k, half, 1)               # k[i - half]
-    rot = jnp.where(lane < half, -ahead, behind)
-    return k * jnp.cos(ang) + rot * jnp.sin(ang)
+    ang = pos.astype(jnp.float32) * freq                     # (dh/2, T)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    parts = []
+    for h in range(n_kv):
+        k1, k2 = kt[h * dh:h * dh + half], kt[h * dh + half:(h + 1) * dh]
+        parts += [k1 * cos - k2 * sin, k2 * cos + k1 * sin]
+    return jnp.concatenate(parts, axis=0)
 
 
-def _value_lanes(kvd: int, v_bits: int) -> jnp.ndarray:
-    """(1, kvd) channel held by each value lane: natural order for int8,
-    [even channels | odd channels] for unpacked int4 codes."""
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, kvd), 1)
+def _channel(lane: jnp.ndarray, kvd: int, v_bits: int) -> jnp.ndarray:
+    """Channel held by each value lane: natural order for int8,
+    [even channels | odd channels] for unpacked int4."""
     if v_bits != 4:
         return lane
     half = kvd // 2
     return jnp.where(lane < half, 2 * lane, 2 * (lane - half) + 1)
 
 
-def _dequant_row(vq_ref, vs_ref, vz_ref, row, chan, *, v_bits: int,
-                 v_group: int) -> jnp.ndarray:
-    """One token's values (1, kvd) f32 in ``chan`` lane order (matches
-    quantization.dequantize)."""
+def _iota(shape, axis: int) -> jnp.ndarray:
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _dot(a, b, dims=((1,), (0,))) -> jnp.ndarray:
+    return jax.lax.dot_general(a, b, (dims, ((), ())), precision=_HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def _dequant(codes, scale_t, zero_t, kvd: int, *, v_bits: int,
+             v_group: int) -> jnp.ndarray:
+    """(T, kvd) f32 values from picked codes (T, code_w) and the group
+    scales and zero points as columns (G, T), in value-lane order (matches
+    quantization.dequantize).  Each group's scale reaches its lanes through
+    one matmul with a 0/1 (G, kvd) matrix: exact, one nonzero term."""
     if v_bits == 4:
-        code = _pick_row(vq_ref, row, jnp.int32)            # (1, kvd/2)
-        vals = jnp.concatenate([(code & 0x0F).astype(jnp.float32),
-                                ((code >> 4) & 0x0F).astype(jnp.float32)],
+        c = codes.astype(jnp.int32)
+        vals = jnp.concatenate([(c & 0x0F).astype(jnp.float32),
+                                ((c >> 4) & 0x0F).astype(jnp.float32)],
                                axis=1)
     else:
-        vals = _pick_row(vq_ref, row, jnp.float32) + 128.0
-    n_groups = vs_ref.shape[2]
-    grp = chan // v_group
-    scale = _spread(_pick_row(vs_ref, row, jnp.float32), grp, n_groups)
-    zero = _spread(_pick_row(vz_ref, row, jnp.float32), grp, n_groups)
-    return vals * scale + zero
+        vals = codes + 128.0
+    shape = (scale_t.shape[0], kvd)
+    spread = (_channel(_iota(shape, 1), kvd, v_bits) // v_group
+              == _iota(shape, 0)).astype(jnp.float32)
+    contract = ((0,), (0,))
+    return vals * _dot(scale_t, spread, contract) + _dot(zero_t, spread,
+                                                         contract)
 
 
-def _recon_kernel(idx_ref, valid_ref, qpos_ref, base_ref, ksel_ref, *rest,
-                  paged: bool, tile: int, n_kv: int, dh: int, group: int,
-                  softcap: float, use_rope: bool, nc: int, v_bits: int,
-                  v_group: int, n_recent: int):
-    if paged:
-        rest = rest[1:]                     # the page table feeds index maps
-    (q_ref, freq_ref, lat_ref, vq_ref, vs_ref, vz_ref, u_ref,
-     m_ref, l_ref, o_ref, m_s, l_s, acc_s) = rest
+def _recon_kernel(*refs, n_prefetch: int, paged: bool, has_ksel: bool,
+                  scale_blocks: bool, tile: int, chunk: int, block: int,
+                  n_kv: int, dh: int, softcap: float, use_rope: bool,
+                  v_bits: int, v_group: int):
+    pre, refs = refs[:n_prefetch], refs[n_prefetch:]
+    tok_ref = pre[0]                        # row of the token: in its row
+    page_ref = pre[1] if paged else None    # of the cache, or in its page
+    ksel_ref = pre[-1] if has_ksel else None
+    (q_ref, lim_ref, freq_ref, pos_ref, vis_ref, u_ref, *refs) = refs
+    if scale_blocks:                        # the block's (G, T) scale columns
+        vs_ref, vz_ref, *refs = refs
+    # the arrays copied here (latents, codes[, scales, zero points]), the
+    # outputs; scratch: a gather buffer per copied array, picked latent and
+    # code tiles, the DMA semaphores, the block-diagonal queries, the
+    # online-softmax state
+    n_copy = 2 if scale_blocks else 4
+    caches, (m_ref, l_ref, o_ref), bufs, picks = (
+        refs[:n_copy], refs[n_copy:n_copy + 3],
+        refs[n_copy + 3:2 * n_copy + 3], refs[2 * n_copy + 3:2 * n_copy + 5])
+    sems, q_s, m_s, l_s, acc_s = refs[2 * n_copy + 5:]
     b_, n_ = pl.program_id(0), pl.program_id(1)
-    kvd = n_kv * dh
+    nb = pl.num_programs(1)
+    step = b_ * nb + n_
+    slot = step % 2
+    rows, kvd = acc_s.shape
+    qg = rows // n_kv
 
+    def gather(b, n, slot):
+        """Start the copies of block (b, n)'s tiles into ``slot``."""
+        def one(t, carry):
+            row = tok_ref[b, n * block + t]
+            lead = page_ref[b, n * block + t] if paged else b
+            at = pl.ds(pl.multiple_of(row - row % tile, tile), tile)
+            srcs = [caches[0].at[lead, at], caches[1].at[lead, at]]
+            if not scale_blocks:
+                lanes = pl.ds(pl.multiple_of(row - row % chunk, chunk), chunk)
+                srcs += [caches[2].at[lead, :, lanes],
+                         caches[3].at[lead, :, lanes]]
+            for a, (src, buf) in enumerate(zip(srcs, bufs)):
+                pltpu.make_async_copy(src, buf.at[slot, t], sems.at[a, slot]
+                                      ).start()
+            return carry
+        jax.lax.fori_loop(0, block, one, 0)
+
+    @pl.when(step == 0)
+    def _first():
+        gather(b_, n_, slot)
+
+    @pl.when(step + 1 < pl.num_programs(0) * nb)
+    def _prefetch():
+        gather((step + 1) // nb, (step + 1) % nb, 1 - slot)
+
+    # row h·qg + i of the queries and of the output belongs to kv head h
     @pl.when(n_ == 0)
     def _init():
         m_s[...] = jnp.full_like(m_s, NEG_INF)
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
+        # queries block-diagonal: row h·qg + i in kv head h's lanes
+        rep = (_iota((dh, kvd), 1) % dh == _iota((dh, kvd), 0))
+        own = _iota((rows, kvd), 1) // dh == _iota((rows, kvd), 0) // qg
+        q_s[...] = jnp.where(own, _dot(q_ref[0], rep.astype(jnp.float32)),
+                             0.0)
 
-    tok = idx_ref[b_, n_]
-    row = jax.lax.rem(tok, tile)
+    for a, buf in enumerate(bufs):          # one wait covers the T copies
+        pltpu.make_async_copy(buf.at[slot], buf.at[slot],
+                              sems.at[a, slot]).wait()
 
-    # ---- 1-2. dequantize the latent, reconstruct k = lat · Uᵀ --------------
-    lat = _pick_row(lat_ref, row, jnp.float32) * ksel_ref[b_, n_]   # (1, r)
-    k = jax.lax.dot_general(
-        lat, u_ref[...].astype(jnp.float32), (((1,), (1,)), ((), ())),
-        precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=jnp.float32)                         # (1, kvd)
+    # ---- pick each token's rows, 8 tokens at a time ------------------------
+    def pick(c, cols):
+        t0 = pl.multiple_of(c * _TILE, _TILE)
+        tiles = [jnp.zeros((_TILE, p.shape[1]), jnp.float32) for p in picks]
+        for i in range(_TILE):
+            j = n_ * block + t0 + i
+            row = tok_ref[b_, j]
+            for a, buf in enumerate(bufs[:2]):
+                x = _pick(_as_f32(buf[slot, t0 + i]), row % tile, 0)
+                if a == 0 and has_ksel:
+                    x = x * ksel_ref[b_, j]
+                tiles[a] = jnp.where(_iota(tiles[a].shape, 0) == i, x,
+                                     tiles[a])
+            if cols:                        # scales copied here
+                at = _iota(cols[0].shape, 1) == t0 + i
+                cols = tuple(
+                    jnp.where(at, _pick(_as_f32(buf[slot, t0 + i]),
+                                        row % chunk, 1), col)
+                    for buf, col in zip(bufs[2:], cols))
+        for p, x in zip(picks, tiles):
+            p[pl.ds(t0, _TILE), :] = x
+        return cols
+    if scale_blocks:
+        jax.lax.fori_loop(0, block // _TILE, pick, ())
+        scale_t, zero_t = (r[0, 0].astype(jnp.float32)
+                           for r in (vs_ref, vz_ref))
+    else:
+        groups = bufs[2].shape[2]
+        scale_t, zero_t = jax.lax.fori_loop(
+            0, block // _TILE, pick,
+            (jnp.zeros((groups, block), jnp.float32),) * 2)  # (G, T) each
 
-    # ---- 3. RoPE at the original position (= base + the cache index) ------
-    pos = tok + base_ref[b_]
+    # ---- 1-2. reconstruct Kᵀ = U · latᵀ, RoPE each column ------------------
+    kt = _dot(u_ref[...].astype(jnp.float32), picks[0][...],
+              ((1,), (1,)))                                  # (kvd, T)
     if use_rope:
-        k = _rope_flat(k, pos, freq_ref[...], dh)
+        kt = _rope_cols(kt, pos_ref[0, 0], freq_ref[...], n_kv, dh)
 
-    # ---- 4. GQA logits of every (query, head) row vs this token ------------
-    logits = _head_sums(q_ref[0] * k, n_kv, dh) * (dh ** -0.5)
+    # ---- 3. every head's logits in one matmul, masked per column -----------
+    s = _dot(q_s[...], kt) * (dh ** -0.5)
     if softcap:
-        logits = softcap * jnp.tanh(logits / softcap)
-    ok = valid_ref[b_, n_] != 0
-    if n_recent:
-        t_of = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 0) // group
-        ok = ok & (pos <= qpos_ref[b_] + t_of - n_recent)
-    logits = jnp.where(ok, logits, NEG_INF)
+        s = softcap * jnp.tanh(s / softcap)
+    s = jnp.where(vis_ref[0, 0] <= lim_ref[0], s, NEG_INF)   # (rows, T)
 
-    # ---- 5. dequant value + online-softmax accumulate ----------------------
+    # ---- 4. dequant values + online-softmax accumulate ---------------------
     m_prev = m_s[...]
-    m_new = jnp.maximum(m_prev, logits)
-    p = jnp.where(logits <= NEG_INF / 2, 0.0, jnp.exp(logits - m_new))
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - m_new))
     alpha = jnp.exp(m_prev - m_new)
-    l_s[...] = l_s[...] * alpha + p
-    chan = _value_lanes(kvd, v_bits)
-    v = _dequant_row(vq_ref, vs_ref, vz_ref, row, chan, v_bits=v_bits,
-                     v_group=v_group)
-    head = chan // dh
-    acc_s[...] = acc_s[...] * _spread(alpha, head, n_kv) \
-        + _spread(p, head, n_kv) * v
+    l_s[...] = l_s[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    v = _dequant(picks[1][...], scale_t, zero_t, kvd, v_bits=v_bits,
+                 v_group=v_group)
+    acc_s[...] = acc_s[...] * alpha + _dot(p, v)
     m_s[...] = m_new
 
-    @pl.when(n_ == nc - 1)
+    @pl.when(n_ == nb - 1)
     def _finish():
         m_ref[0] = m_s[...]
         l_ref[0] = l_s[...]
-        o_ref[0] = acc_s[...]
+        # each row keeps its own head's channels, in channel order
+        ch = _channel(_iota((rows, kvd), 1), kvd, v_bits)
+        own = ch // dh == _iota((rows, kvd), 0) // qg
+        sel = _channel(_iota((kvd, dh), 0), kvd, v_bits) % dh \
+            == _iota((kvd, dh), 1)
+        o_ref[0] = _dot(jnp.where(own, acc_s[...], 0.0),
+                        sel.astype(jnp.float32))
 
 
 def _recon_call(q, k_lat, k_scale, v_q, v_scale, v_zero, u, idx, valid,
@@ -216,107 +326,138 @@ def _recon_call(q, k_lat, k_scale, v_q, v_scale, v_zero, u, idx, valid,
     b, ql, h, dh = q.shape
     kvd = n_kv * dh
     group = h // n_kv
+    qg = ql * group
+    rows = n_kv * qg
     nc = idx.shape[1]
-    r = k_lat.shape[-1]
     paged = page_table is not None
+    has_ksel = k_scale is not None
 
     qpos = jnp.broadcast_to(jnp.asarray(q_pos, jnp.int32).reshape(-1), (b,))
     base = jnp.zeros((b,), jnp.int32) if pos_base is None \
         else jnp.broadcast_to(jnp.asarray(pos_base, jnp.int32), (b,))
-    idx_i = idx.astype(jnp.int32)
 
-    # RoPE'd queries as a (Q·group, kvd) tile per row: row t·group + j,
-    # lanes of kv head g hold query head g·group + j
+    # the value group scales, sequence axis last: (B or pages, G, S or ps),
+    # the layout XLA keeps them in, so the view is free
+    scales = [jnp.swapaxes(x, 1, 2) for x in (v_scale, v_zero)]
+    seq = page_size if paged else k_lat.shape[1]
+    tile, chunk = math.gcd(seq, _TILE), _LANES
+    # Mosaic copies no lane slice narrower than 128 (nor a whole lane axis
+    # that 128 does not divide): there the selected tokens' scales are
+    # gathered here and arrive as (G, T) blocks
+    scale_blocks = seq % chunk != 0
+    caches = (k_lat, v_q) if scale_blocks else (k_lat, v_q, *scales)
+    block, nb = _block_size(nc, [(tile, x.shape[-1], x.dtype)
+                                 for x in caches[:2]]
+                            + [(x.shape[1], chunk, x.dtype)
+                               for x in caches[2:]])
+    pad = ((0, 0), (0, nb * block - nc))
+    idx_i = jnp.pad(idx.astype(jnp.int32), pad)
+    valid_i = jnp.pad(valid, pad)
+
+    # where each token's row lives: (page,) row; its int8 latent scale
+    if paged:
+        mp = page_table.shape[1]
+        lp = jnp.clip(idx_i // page_size, 0, mp - 1)       # invalid: clamp
+        page = jnp.clip(jnp.take_along_axis(
+            page_table.astype(jnp.int32), lp, axis=1), 0, k_lat.shape[0] - 1)
+        row = jnp.clip(idx_i - lp * page_size, 0, page_size - 1)
+        prefetch = [row, page]
+
+        def sel(x):                 # the selected tokens' rows of x
+            return x[page, row]
+    else:
+        row = jnp.clip(idx_i, 0, k_lat.shape[1] - 1)
+        prefetch = [row]
+
+        def sel(x):
+            return jnp.take_along_axis(
+                x, row.reshape(*row.shape, *(1,) * (x.ndim - 2)), axis=1)
+    if has_ksel:
+        prefetch.append(sel(k_scale).astype(jnp.float32))
+    # (B, n_blocks, G, T): each block's scale columns
+    scale_in = [sel(x).reshape(b, nb, block, -1).swapaxes(2, 3)
+                for x in (v_scale, v_zero)] if scale_blocks else []
+
+    # RoPE'd queries, row h·qg + t·group + j: query head h·group + j at
+    # position t (the kernel spreads each row into its kv head's lanes)
     q32 = q.astype(jnp.float32)
     if use_rope:
         q32 = _rope(q32, qpos[:, None] + jnp.arange(ql, dtype=jnp.int32),
                     theta)
-    q_t = q32.reshape(b, ql, n_kv, group, dh).transpose(0, 1, 3, 2, 4) \
-        .reshape(b, ql * group, kvd)
-    half = dh // 2
-    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
-    freq = jnp.tile(freqs, 2 * n_kv).reshape(1, kvd)
+    q_rows = q32.reshape(b, ql, n_kv, group, dh).transpose(0, 2, 1, 3, 4) \
+        .reshape(b, rows, dh)
 
-    # per-token int8 latent scales of the selected rows -> SMEM scalars
-    if paged:
-        mp = page_table.shape[1]
-        n_pages = k_lat.shape[0]
-        pt = page_table.astype(jnp.int32)
-        tile = page_size
-        phys = jnp.clip(jnp.take_along_axis(
-            pt, jnp.minimum(idx_i // page_size, mp - 1), axis=1),
-            0, n_pages - 1)                                    # (B, N_c)
-        if k_scale is None:
-            ksel = jnp.ones((b, nc), jnp.float32)
-        else:
-            ksel = k_scale[phys, idx_i % page_size].astype(jnp.float32)
-
-        def blk(b_, n_, i_, v_, p_, bb_, ks_, pt_):
-            lp = jnp.minimum(i_[b_, n_] // page_size, mp - 1)  # invalid: clamp
-            return jnp.clip(pt_[b_, lp], 0, n_pages - 1)
-        prefetch = (idx_i, valid.astype(jnp.int32), qpos, base, ksel, pt)
+    # per column: RoPE position, and the position a row must reach to see
+    # it (_NEVER if invalid); per row: the last position it sees
+    pos = base[:, None] + idx_i
+    vis = jnp.where(valid_i, pos, _NEVER)
+    if n_recent:
+        t_of = jnp.arange(qg, dtype=jnp.int32) // group
+        lim = qpos[:, None] + t_of - n_recent                 # (B, qg)
     else:
-        s = k_lat.shape[1]
-        tile = math.gcd(s, _TILE)
-        if k_scale is None:
-            ksel = jnp.ones((b, nc), jnp.float32)
-        else:
-            ksel = jnp.take_along_axis(k_scale, jnp.clip(idx_i, 0, s - 1),
-                                       axis=1).astype(jnp.float32)
-
-        def blk(b_, n_, i_, v_, p_, bb_, ks_):
-            return b_, jnp.minimum(i_[b_, n_] // tile, s // tile - 1)
-        prefetch = (idx_i, valid.astype(jnp.int32), qpos, base, ksel)
-
-    def cache_spec(width):
-        if paged:
-            return pl.BlockSpec((1, tile, width),
-                                lambda *a: (blk(*a), 0, 0))
-        return pl.BlockSpec((1, tile, width), lambda *a: (*blk(*a), 0))
-
-    def whole(shape):
-        return pl.BlockSpec(shape, lambda *a: (0,) * len(shape))
+        lim = jnp.full((b, qg), _NEVER - 1, jnp.int32)
+    lim = jnp.broadcast_to(lim[:, None], (b, n_kv, qg)).reshape(b, rows, 1)
+    half = dh // 2
+    freq = (1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+            ).reshape(half, 1)
 
     def per_row(shape):
-        return pl.BlockSpec((1, *shape), lambda b_, *a: (b_,) + (0,) * len(shape))
+        return pl.BlockSpec((1, *shape),
+                            lambda b_, n_, *_: (b_,) + (0,) * len(shape))
 
-    qg = ql * group
+    def per_block(rows, width):
+        return pl.BlockSpec((1, 1, rows, width),
+                            lambda b_, n_, *_: (b_, n_, 0, 0))
+
+    def whole(shape):
+        return pl.BlockSpec(shape, lambda *_: (0,) * len(shape))
+
+    any_ = pl.BlockSpec(memory_space=pl.ANY)
     kernel = functools.partial(
-        _recon_kernel, paged=paged, tile=tile, n_kv=n_kv, dh=dh, group=group,
-        softcap=softcap, use_rope=use_rope, nc=nc, v_bits=v_bits,
-        v_group=v_group, n_recent=n_recent)
+        _recon_kernel, n_prefetch=len(prefetch), paged=paged,
+        has_ksel=has_ksel, scale_blocks=scale_blocks, tile=tile, chunk=chunk,
+        block=block, n_kv=n_kv, dh=dh, softcap=softcap, use_rope=use_rope,
+        v_bits=v_bits, v_group=v_group)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
-        grid=(b, nc),
-        in_specs=[per_row((qg, kvd)), whole((1, kvd)), cache_spec(r),
-                  cache_spec(v_q.shape[-1]), cache_spec(v_scale.shape[-1]),
-                  cache_spec(v_zero.shape[-1]), whole(u.shape)],
-        out_specs=[per_row((qg, n_kv)), per_row((qg, n_kv)),
-                   per_row((qg, kvd))],
-        scratch_shapes=[pltpu.VMEM((qg, n_kv), jnp.float32),
-                        pltpu.VMEM((qg, n_kv), jnp.float32),
-                        pltpu.VMEM((qg, kvd), jnp.float32)],
+        grid=(b, nb),
+        in_specs=[per_row((rows, dh)), per_row((rows, 1)),
+                  whole((half, 1)), per_block(1, block), per_block(1, block),
+                  whole(u.shape)]
+        + [per_block(*x.shape[2:]) for x in scale_in] + [any_] * len(caches),
+        out_specs=[per_row((rows, 1)), per_row((rows, 1)),
+                   per_row((rows, dh))],
+        scratch_shapes=[pltpu.VMEM((2, block, tile, a.shape[-1]), a.dtype)
+                        for a in caches[:2]]
+        + [pltpu.VMEM((2, block, a.shape[1], chunk), a.dtype)
+           for a in caches[2:]]
+        + [pltpu.VMEM((block, a.shape[-1]), jnp.float32) for a in caches[:2]]
+        + [pltpu.SemaphoreType.DMA((len(caches), 2)),
+           pltpu.VMEM((rows, kvd), jnp.float32),
+           pltpu.VMEM((rows, 1), jnp.float32),
+           pltpu.VMEM((rows, 1), jnp.float32),
+           pltpu.VMEM((rows, kvd), jnp.float32)],
     )
+    blocks = lambda x: x.reshape(b, nb, 1, block)           # noqa: E731
     m, l, o = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((b, qg, n_kv), jnp.float32),
-                   jax.ShapeDtypeStruct((b, qg, n_kv), jnp.float32),
-                   jax.ShapeDtypeStruct((b, qg, kvd), jnp.float32)],
+        out_shape=[jax.ShapeDtypeStruct((b, rows, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((b, rows, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((b, rows, dh), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret_mode(),
         name="sals_recon_attend_paged" if paged else "sals_recon_attend",
-    )(*prefetch, q_t, freq, k_lat, v_q, v_scale, v_zero, u)
+    )(*prefetch, q_rows, lim, freq, blocks(pos), blocks(vis), u, *scale_in,
+      *caches)
 
-    if v_bits == 4:                 # [even | odd] lanes -> channel order
-        o = jnp.stack([o[..., :kvd // 2], o[..., kvd // 2:]], axis=-1) \
-            .reshape(b, qg, kvd)
-
-    def heads(x):                   # (B, Q·group, n_kv, ·) -> (B, Q, H, ·)
-        x = x.reshape(b, ql, group, n_kv, -1).transpose(0, 1, 3, 2, 4)
+    def heads(x):               # (B, n_kv·Q·group, ·) -> (B, Q, H, ·)
+        x = x.reshape(b, n_kv, ql, group, -1).transpose(0, 2, 1, 3, 4)
         return x.reshape(b, ql, h, -1)
 
-    return (heads(m[..., None])[..., 0], heads(l[..., None])[..., 0],
-            heads(o.reshape(b, qg, n_kv, dh)))
+    return heads(m)[..., 0], heads(l)[..., 0], heads(o)
 
 
 _STATIC = ("n_kv", "v_bits", "v_group", "theta", "softcap", "use_rope")
@@ -364,12 +505,13 @@ def sparse_recon_attention_paged_pallas(
 
     Cache operands are physical page pools (``k_lat (n_pages, ps, r)``,
     ``v_q (n_pages, ps, code_w)``, ...); ``idx`` holds LOGICAL positions;
-    ``page_table`` (B, max_pages) rides as a scalar-prefetch operand and
-    every cache index_map resolves page ``idx // ps`` through it.  The DMA
-    unit is the whole page — sorted indices make consecutive same-page
-    steps reuse the resident block, so the selected-token HBM traffic is
-    per page touched.  Bit-identical to the dense kernel given the same
-    idx order (per-token math is unchanged).
+    ``page_table`` (B, max_pages) maps each selected token's page
+    ``idx // ps`` to its physical page, which rides as a scalar-prefetch
+    operand; the token's copies are the tile of 8 rows and the chunk of
+    scales holding it in that page, as in the dense kernel (pages under
+    128 rows: the scales arrive as blocks).  Bit-identical
+    to the dense kernel given the same idx order (per-token math is
+    unchanged).
     """
     m, l, o = _recon_call(
         q[:, None], k_lat, k_scale, v_q, v_scale, v_zero, u, idx, valid,
@@ -416,8 +558,8 @@ def sparse_recon_attention_window_paged_pallas(
         pos_base: Optional[jnp.ndarray] = None
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Paged twin of :func:`sparse_recon_attention_window_pallas`: cache
-    operands are physical page pools, ``idx`` stays logical, and sorted
-    indices keep the whole-page DMA once-per-page-touched.  Bit-identical
+    operands are physical page pools, ``idx`` stays logical, and each
+    token's copies read its page through the page table.  Bit-identical
     to the dense windowed kernel given the same idx order."""
     return _recon_call(
         q, k_lat, k_scale, v_q, v_scale, v_zero, u, idx, valid, q_pos,

@@ -8,6 +8,7 @@ from repro.core import quantization as qz
 from repro.kernels import ops, ref
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.latent_score import latent_score_pallas, latent_topk_pallas
+from repro.kernels import sparse_recon_attention as sra
 from repro.kernels.sparse_recon_attention import sparse_recon_attention_pallas
 
 KEY = jax.random.PRNGKey(0)
@@ -283,6 +284,93 @@ def test_fused_sra_positions_are_indices():
     y1 = np.asarray(o1) / np.asarray(l1)[..., None]
     y2 = np.asarray(o2) / np.asarray(l2)[..., None]
     np.testing.assert_allclose(y1, y2, rtol=1e-4, atol=1e-4)
+
+
+# (entry point, N_c, V bits, int8 latents): N_c under one block of T
+# tokens (one padded block) and N_c over one block, not a multiple of T
+# (pad entries in the last block)
+BLOCKED_CASES = [
+    ("dense", 42, 8, False),
+    ("dense", 200, 4, True),
+    ("paged", 200, 8, True),
+    ("paged", 42, 4, False),
+    ("window", 136, 8, True),
+    ("window_paged", 136, 4, False),
+]
+
+
+@pytest.mark.parametrize("entry,nc,v_bits,k_int8", BLOCKED_CASES)
+def test_blocked_recon_matches_oracle(entry, nc, v_bits, k_int8):
+    """The kernel takes the selection T tokens a grid step: every entry
+    point matches its oracle with the last block padded, a row whose
+    entries are all invalid, selected tokens sharing an 8-row tile, and
+    tokens in the last tile of the cache."""
+    b, s, ps, n_kv, dh, r, vg = 3, 256, 16, 2, 64, 32, 32
+    h, kvd = 2 * n_kv, n_kv * dh
+    window, paged = entry.startswith("window"), entry.endswith("paged")
+    ks = jax.random.split(jax.random.fold_in(KEY, nc + v_bits), 6)
+    ql = 3 if window else 1
+    q = jax.random.normal(ks[0], (b, ql, h, dh), jnp.float32)
+    lat = jax.random.normal(ks[1], (b, s, r))
+    if k_int8:
+        k_lat, k_scale = qz.quantize_latent_int8(lat)
+    else:
+        k_lat, k_scale = lat.astype(jnp.bfloat16), None
+    vq = qz.quantize(jax.random.normal(ks[2], (b, s, kvd)) * 2.0, v_bits,
+                     vg)
+    u = jax.random.normal(ks[3], (kvd, r), jnp.float32)
+    block, nb = sra._block_size(
+        nc, [(8, x.shape[-1], x.dtype) for x in (k_lat, vq["q"])]
+        + [(kvd // vg, 128, vq["scale"].dtype)] * 2)
+    assert nb * block > nc and (nb > 1) == (nc > 128)
+
+    rng = np.random.default_rng(nc)
+    pinned = [16, 17, 23, 40, 41, s - 8, s - 2, s - 1]
+    rest = np.setdiff1d(np.arange(s), pinned)
+    idx = np.stack([np.sort(np.concatenate(
+        [pinned, rng.choice(rest, nc - len(pinned), replace=False)]))
+        for _ in range(b)]).astype(np.int32)
+    valid = rng.random((b, nc)) < 0.85
+    valid |= np.isin(idx, pinned)
+    valid[2] = False                          # a row with nothing valid
+    q_pos = jnp.asarray([s + 7, s - 3, s + 7], jnp.int32)
+    args = [q if window else q[:, 0], k_lat, k_scale, vq["q"], vq["scale"],
+            vq["zero"], u, jnp.asarray(idx), jnp.asarray(valid), q_pos]
+    kw = dict(n_kv=n_kv, v_bits=v_bits, v_group=vg, theta=1e4)
+    if window:
+        kw["n_recent"] = 8
+    if paged:                 # the rows scattered over a permuted page pool
+        mp = s // ps
+        pt = rng.permutation(b * mp + 2)[:b * mp].reshape(b, mp) + 1
+
+        def pool(x):
+            if x is None:
+                return None
+            x = np.asarray(x).reshape(b * mp, ps, *x.shape[2:])
+            out = np.zeros((b * mp + 3, *x.shape[1:]), x.dtype)
+            out[pt.reshape(-1)] = x
+            return jnp.asarray(out)
+        args[1:6] = [pool(x) for x in args[1:6]]
+        kw.update(page_table=jnp.asarray(pt, jnp.int32), page_size=ps)
+    kernel, oracle = {
+        "dense": (sparse_recon_attention_pallas,
+                  ref.sparse_recon_attention_fused_ref),
+        "paged": (sra.sparse_recon_attention_paged_pallas,
+                  ref.sparse_recon_attention_paged_ref),
+        "window": (sra.sparse_recon_attention_window_pallas,
+                   ref.sparse_recon_attention_fused_window_ref),
+        "window_paged": (sra.sparse_recon_attention_window_paged_pallas,
+                         ref.sparse_recon_attention_window_paged_ref),
+    }[entry]
+    m1, l1, o1 = (np.asarray(x) for x in kernel(*args, **kw))
+    m2, l2, o2 = (np.asarray(x) for x in oracle(*args, **kw))
+    np.testing.assert_allclose(m1, m2, rtol=1e-3, atol=1e-3)
+    np.testing.assert_allclose(l1, l2, rtol=1e-3, atol=1e-3)
+    np.testing.assert_allclose(o1 / np.maximum(l1, 1e-30)[..., None],
+                               o2 / np.maximum(l2, 1e-30)[..., None],
+                               rtol=1e-3, atol=1e-3)
+    assert np.all(l1[2] == 0.0) and np.all(o1[2] == 0.0)
+    assert not np.isnan(m1).any()
 
 
 # ---------------------------------------------------------------------------

@@ -100,26 +100,24 @@ def test_latent_topk_compiles_for_v5e(one_chip, for_tpu, setting, paged):
         assert "sals_latent_topk" in hlo
 
 
-@pytest.mark.parametrize("setting", sorted(SETTINGS))
-@pytest.mark.parametrize("variant", ["dense", "paged", "window",
-                                     "window_paged"])
-def test_sparse_recon_attention_compiles_for_v5e(one_chip, for_tpu,
-                                                 setting, variant):
+def _compile_recon(one_chip, setting, variant, b, s, nc, page=PAGE):
+    """Compile one reconstruct-attend entry point at (B, S, N_c), paged
+    variants with pages of ``page`` rows, and return its HLO text."""
     r, _, v_bits, k8 = SETTINGS[setting]
     code_w, code_t = (KVD, jnp.int8) if v_bits == 8 else \
         (KVD // 2, jnp.uint8)
     paged = variant.endswith("paged")
-    rows = (N_PAGES, PAGE) if paged else (B, S)
-    q_shape = (B, 4, H, DH) if variant.startswith("window") else (B, H, DH)
+    rows = (b * s // page + 1, page) if paged else (b, s)
+    q_shape = (b, 4, H, DH) if variant.startswith("window") else (b, H, DH)
     k_lat, scale = _latents(one_chip, rows, r, k8)
     args = [_spec(one_chip, q_shape, jnp.bfloat16), k_lat, scale,
             _spec(one_chip, (*rows, code_w), code_t),
             _spec(one_chip, (*rows, KVD // 64), jnp.bfloat16),
             _spec(one_chip, (*rows, KVD // 64), jnp.bfloat16),
             _spec(one_chip, (KVD, r), jnp.bfloat16),
-            _spec(one_chip, (B, N_C), jnp.int32),
-            _spec(one_chip, (B, N_C), jnp.bool_),
-            _spec(one_chip, (B,), jnp.int32)]
+            _spec(one_chip, (b, nc), jnp.int32),
+            _spec(one_chip, (b, nc), jnp.bool_),
+            _spec(one_chip, (b,), jnp.int32)]
     kw = dict(n_kv=N_KV, v_bits=v_bits, v_group=64, theta=1e6)
     fn = {"dense": sra.sparse_recon_attention_pallas,
           "paged": sra.sparse_recon_attention_paged_pallas,
@@ -129,16 +127,41 @@ def test_sparse_recon_attention_compiles_for_v5e(one_chip, for_tpu,
     if variant.startswith("window"):
         kw["n_recent"] = 64
     if paged:
-        args.append(_spec(one_chip, (B, S // PAGE), jnp.int32))
+        args.append(_spec(one_chip, (b, s // page), jnp.int32))
 
         def call(q, k, ks, vq, vs, vz, u, i, v, p, t):
             return fn(q, k, ks, vq, vs, vz, u, i, v, p, page_table=t,
-                      page_size=PAGE, **kw)
+                      page_size=page, **kw)
     else:
         def call(q, k, ks, vq, vs, vz, u, i, v, p):
             return fn(q, k, ks, vq, vs, vz, u, i, v, p, **kw)
-    hlo = _compile(call, *args)
+    return _compile(call, *args)
+
+
+@pytest.mark.parametrize("setting", sorted(SETTINGS))
+@pytest.mark.parametrize("variant", ["dense", "paged", "window",
+                                     "window_paged"])
+def test_sparse_recon_attention_compiles_for_v5e(one_chip, for_tpu,
+                                                 setting, variant):
+    hlo = _compile_recon(one_chip, setting, variant, B, S, N_C)
     assert "sals_recon_attend" in hlo
+
+
+def test_sparse_recon_attention_compiles_for_v5e_at_32k(one_chip, for_tpu):
+    """The long-context decode shapes: 4 rows of a 32k cache, N_c 864
+    (the paper's 32k budget), SALS-25% with int8 V, dense cache."""
+    hlo = _compile_recon(one_chip, "sals25", "dense", 4, 32768, 864)
+    assert "sals_recon_attend" in hlo
+
+
+@pytest.mark.parametrize("variant", ["paged", "window_paged"])
+@pytest.mark.parametrize("page", [16, 64])
+def test_sparse_recon_attention_compiles_for_v5e_small_pages(
+        one_chip, for_tpu, variant, page):
+    """Pages under 128 rows: the value scales' sequence axis is narrower
+    than a copy may slice, so their selected columns arrive as blocks."""
+    hlo = _compile_recon(one_chip, "sals25", variant, B, S, N_C, page=page)
+    assert "sals_recon_attend_paged" in hlo
 
 
 def test_flash_attention_compiles_for_v5e(one_chip, for_tpu):
