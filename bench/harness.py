@@ -5,13 +5,17 @@ The system under test is ``launch/serve.build``'s scheduler and engine,
 driven through ``RequestScheduler.run`` in continuous mode; the benchmark
 submits requests, stamps every committed token through ``Request.on_token``
 on the host clock, and cancels what is still running when it is done.
+
+Everything that depends on the architecture comes from the two modules the
+configuration file names: ``"architecture"`` (sizes, weights, projector,
+the program's tree and configs, counts; see ``bench/archs/``) and
+``"reference"`` (``hidden`` and ``head_stats``; see ``bench/reference/``).
 """
 from __future__ import annotations
 
 import collections
 import dataclasses
 import gc
-import importlib.util
 import json
 import os
 import sys
@@ -20,10 +24,8 @@ from typing import List, Optional
 
 import numpy as np
 
-from bench import counts, model, trace
-from bench.model import (Arch, load_config, load_json, make_projector,
-                         make_weights, program_configs, program_params)
-from bench.reference import dense_gqa
+from bench import model, trace
+from bench.model import load_config, load_json, load_module, seed_words
 from bench.traffic import generate, load_traffic
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -128,11 +130,7 @@ def queue_trend(ctx: Context) -> str:
 
 
 def _read_metric(name: str, ctx: Context):
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name}", model.path("metrics", f"{name}.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read(ctx)
+    return load_module(model.path("metrics", f"{name}.py")).read(ctx)
 
 
 def metrics_for(bench: dict, cell: dict, traced: bool) -> list:
@@ -367,7 +365,6 @@ def pick_sample(recs: List[Rec], traffic: dict, seed: int) -> List[Rec]:
     """The longest finished request, and others drawn from the seed until
     ``compare_tokens`` served tokens and ``compare_requests`` requests are
     in."""
-    from bench.model import seed_words
     want = int(traffic["compare_tokens"])
     n_min = int(traffic.get("compare_requests", 1))
     done = [r for r in recs if r.tokens and (
@@ -385,12 +382,12 @@ def pick_sample(recs: List[Rec], traffic: dict, seed: int) -> List[Rec]:
     return sample
 
 
-def compare(w, u, arch: Arch, traffic: dict, sample: List[Rec],
+def compare(ref, w, u, arch, traffic: dict, sample: List[Rec],
             control: bool) -> dict:
     """Widest gap by which a served token's reference logit lies below the
     reference's best, over every served token of the sample; with
     ``control``, the same for the tokens the fp8 reference puts first."""
-    sp = dense_gqa.spec_for(arch, traffic["n_critical"])
+    nc = int(traffic["n_critical"])
     bucket = int(traffic["reference_bucket"])
     out = {"max_logit_gap": 0.0, "tokens": 0, "requests": len(sample)}
     if control:
@@ -402,17 +399,15 @@ def compare(w, u, arch: Arch, traffic: dict, sample: List[Rec],
         rows = np.full((-(-n // 256) * 256,), p - 1 + n - 1, np.int32)
         rows[:n] = p - 1 + np.arange(n)
         served = np.asarray(r.tokens, np.int32)
-        hid = dense_gqa.final_hidden(w, u["u"], arch.sals_layers, sp, toks,
-                                     p, rows, t_len)
-        st = dense_gqa.head_stats(w, sp, hid, served)
+        hid = ref.hidden(w, u, arch, nc, toks, p, rows, t_len)
+        st = ref.head_stats(w, arch, hid, served)
         out["max_logit_gap"] = max(out["max_logit_gap"],
                                    float(np.max(st["max"] - st["at"])))
         out["tokens"] += n
         if control:
-            hc = dense_gqa.final_hidden(w, u["u"], arch.sals_layers, sp, toks,
-                                        p, rows, t_len, fp8=True)
-            pick = dense_gqa.head_stats(w, sp, hc, served, fp8=True)["argmax"]
-            sc = dense_gqa.head_stats(w, sp, hid, pick)
+            hc = ref.hidden(w, u, arch, nc, toks, p, rows, t_len, fp8=True)
+            pick = ref.head_stats(w, arch, hc, served, fp8=True)["argmax"]
+            sc = ref.head_stats(w, arch, hid, pick)
             out["control_gap"] = max(out["control_gap"],
                                      float(np.max(sc["max"] - sc["at"])))
     return out
@@ -432,7 +427,8 @@ def run(cell_name: str, seed: int, seconds: float, traced: bool, t_start:
     cell = find_cell(bench, cell_name)
     c = load_config(cell["config"])
     t = load_traffic(cell["traffic"])
-    arch = Arch(c)
+    am, ref = load_module(c["architecture"]), load_module(c["reference"])
+    arch = am.Arch(c)
 
     import jax
     devs = jax.devices()
@@ -454,15 +450,14 @@ def run(cell_name: str, seed: int, seconds: float, traced: bool, t_start:
             raise RuntimeError("the program chose another compile cache")
 
     t_w = now()
-    w = make_weights(arch, seed)
-    u = make_projector(arch, w)
+    w = am.make_weights(arch, seed)
+    u = am.make_projector(arch, w)
     jax.block_until_ready((w, u))
     log(f"[setup] weights and projector {now() - t_w:.2f}s: "
-        f"{arch.param_count() / 1e9:.3f}B params, {arch.n_layers} layers "
-        f"({arch.n_sals} SALS, rank {arch.rank}, score rank {arch.r_star})")
-    mcfg, scfg = program_configs(arch, c, t)
+        f"{arch.summary()}")
+    mcfg, scfg = am.program_configs(arch, c, t)
     t_b = now()
-    sched, _, _ = build(mcfg, scfg, params=program_params(arch, w),
+    sched, _, _ = build(mcfg, scfg, params=am.program_params(arch, w),
                         projectors=u, log=log)
     log(f"[setup] build {now() - t_b:.2f}s")
     if fault is not None:
@@ -498,7 +493,7 @@ def run(cell_name: str, seed: int, seconds: float, traced: bool, t_start:
     ctx = Context(recs=recs, t0=drv.t0, t1=drv.t1, run_end=drv.run_end,
                   seconds=drv.t1 - drv.t0, setup_s=setup_s, steps=drv.steps,
                   extract=extract, tr0=drv.tr0, tr1=drv.tr1, arch=arch,
-                  traffic=t, counts=counts, trace=trace,
+                  traffic=t, counts=am.counts, trace=trace,
                   peaks=load_json("peaks.json"), device_kind=dev.device_kind)
     attempted = len(ctx.due_in_window()) if t["kind"] == "open_loop" \
         else len(recs)
@@ -518,7 +513,7 @@ def run(cell_name: str, seed: int, seconds: float, traced: bool, t_start:
     del sched, drv
     gc.collect()
     t_ref = now()
-    cmp = compare(w, u, arch, t, sample, control)
+    cmp = compare(ref, w, u, arch, t, sample, control)
     limit_path = model.path("limits", f"{cell_name}.json")
     limit = load_json(limit_path)["max_logit_gap"] \
         if os.path.exists(limit_path) else None

@@ -26,6 +26,10 @@ arithmetic is float32.
 ``fp8=True`` is the control: every linear layer and the LM head take
 their operands through float8 e4m3 (activations scaled per row, weights
 per output column), the step below the configuration's bfloat16.
+
+The harness calls :func:`hidden` and :func:`head_stats`, the two names
+every reference module gives it, with the canonical weights and projector
+that the configuration's architecture module draws.
 """
 from __future__ import annotations
 
@@ -55,7 +59,6 @@ class Spec:
     r_star: int
     v_bits: int
     v_group: int
-    tied: bool
 
     @property
     def group(self) -> int:
@@ -70,7 +73,7 @@ def spec_for(arch, n_critical: int) -> Spec:
     s = arch.sals
     return Spec(arch.n_heads, arch.n_kv, arch.dh, arch.eps, arch.theta,
                 int(s["n_sink"]), int(s["n_recent"]), int(n_critical),
-                arch.r_star, int(s["v_bits"]), int(s["v_group"]), arch.tied)
+                arch.r_star, int(s["v_bits"]), int(s["v_group"]))
 
 
 def _bf16(x):
@@ -248,6 +251,15 @@ def final_hidden(w, u, sals_layers, sp: Spec, tokens: np.ndarray,
     return _final(x, jnp.asarray(rows, jnp.int32), w["final_norm"], sp.eps)
 
 
+def hidden(w, proj, arch, n_critical: int, tokens: np.ndarray,
+           n_prompt: int, rows: np.ndarray, t_len: int, fp8=False):
+    """:func:`final_hidden` of a configuration: its SALS layers, its
+    projector, and a decode budget of ``n_critical`` tokens."""
+    return final_hidden(w, proj["u"], arch.sals_layers,
+                        spec_for(arch, n_critical), tokens, n_prompt, rows,
+                        t_len, fp8)
+
+
 @functools.partial(jax.jit, static_argnums=(3, 4))
 def _head_stats(hid, head, toks, tied: bool, fp8: bool):
     w = head.T if tied else head
@@ -256,16 +268,17 @@ def _head_stats(hid, head, toks, tied: bool, fp8: bool):
     return jnp.max(logits, -1), at, jnp.argmax(logits, -1).astype(jnp.int32)
 
 
-def head_stats(w, sp: Spec, hid, toks: np.ndarray, fp8=False) -> dict:
+def head_stats(w, arch, hid, toks: np.ndarray, fp8=False) -> dict:
     """Per row: the largest logit, the logit of ``toks``, and the argmax."""
-    head = w["embed"] if sp.tied else w["head"]
+    head = w["embed"] if arch.tied else w["head"]
     out = {"max": [], "at": [], "argmax": []}
     step = 256
     for i in range(0, hid.shape[0], step):
         h = hid[i:i + step]
         t = np.zeros((h.shape[0],), np.int32)
         t[:len(toks[i:i + step])] = toks[i:i + step]
-        mx, at, am = _head_stats(h, head, jnp.asarray(t), sp.tied, bool(fp8))
+        mx, at, am = _head_stats(h, head, jnp.asarray(t), arch.tied,
+                                 bool(fp8))
         out["max"].append(np.asarray(mx))
         out["at"].append(np.asarray(at))
         out["argmax"].append(np.asarray(am))

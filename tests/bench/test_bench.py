@@ -5,15 +5,20 @@
   that stay at or under 100% on a recorded chip trace;
 * the byte and operation counts, and the traffic generator;
 * ``BENCHMARK.json`` against the benchmark's rules;
+* the dense GQA weight draw: untied, what it drew before configurations
+  named their architecture module; tied, at the head's scale;
 * whole runs at a tiny size (the chip check skipped): a configuration,
-  traffic mix and metric added as files alone; the program passes the
-  correctness limit and the fp8 control fails it; every fault the cells
-  can have, planted under the timed path, makes ``correct`` false;
+  traffic mix and metric added as files alone, and an architecture module
+  and reference added as files alone; the program passes the correctness
+  limit and the fp8 control fails it, with an untied and a tied head;
+  every fault the cells can have, planted under the timed path, makes
+  ``correct`` false;
 * ``bench/run.py`` exits non-zero with no result off the chip, and without
   the program beside it.
 """
 from __future__ import annotations
 
+import hashlib
 import json
 import os
 import re
@@ -99,16 +104,23 @@ def read_metric(name, ctx):
     return harness._read_metric(name, ctx)
 
 
+def arch_module(cfg):
+    """The architecture module a configuration names."""
+    return model.load_module(cfg["architecture"])
+
+
 def recording_context(path):
     from bench import harness
     with open(path) as f:
         rec = json.load(f)
-    arch = model.Arch(model.load_config(rec["config"]))
+    cfg = model.load_config(rec["config"])
+    am = arch_module(cfg)
     steps = [tuple(s) for s in rec["steps"]]
     return harness.Context(
         recs=[], extract=rec["extract"], steps=steps,
         tr0=min(s[0] for s in steps), tr1=max(s[0] for s in steps),
-        arch=arch, traffic=load_traffic(rec["traffic"]), counts=counts,
+        arch=am.Arch(cfg), traffic=load_traffic(rec["traffic"]),
+        counts=am.counts,
         trace=trace, peaks=model.load_json("peaks.json"),
         device_kind=rec["device_kind"])
 
@@ -149,9 +161,14 @@ def test_unknown_device_is_an_error():
 # counts and traffic
 # ---------------------------------------------------------------------------
 
+def tiny_config(name="tiny-gqa"):
+    with open(os.path.join(DATA, "configs", f"{name}.json")) as f:
+        return json.load(f)
+
+
 def tiny_arch():
-    return model.Arch(json.load(open(os.path.join(DATA, "configs",
-                                                  "tiny-gqa.json"))))
+    cfg = tiny_config()
+    return arch_module(cfg).Arch(cfg)
 
 
 def test_counts_by_hand():
@@ -227,7 +244,11 @@ def test_benchmark_json_follows_the_rules():
         assert NAME.match(c["name"])
         f = os.path.join(ROOT, c["file"])
         assert os.path.isfile(f)
-        assert model.load_config(c["name"])["reduced"] == c["reduced"]
+        cfg = model.load_config(c["name"])
+        assert cfg["reduced"] == c["reduced"]
+        for key in ("architecture", "reference"):   # files of the benchmark
+            assert os.path.isfile(os.path.join(ROOT, cfg[key])), cfg[key]
+            assert any(cfg[key].startswith(p + "/") for p in b["paths"])
     cells = set()
     for w in b["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
@@ -269,6 +290,49 @@ def test_benchmark_json_follows_the_rules():
 
 
 # ---------------------------------------------------------------------------
+# the dense GQA weight draw
+# ---------------------------------------------------------------------------
+
+# sha256 of make_weights and make_projector at tiny-gqa, seed 2**31 + 7,
+# recorded on the commit before configurations named their architecture
+# module (4b68c7c): untied configurations draw what they drew then
+UNTIED_DRAW_SHA256 = \
+    "121ca451bd0be3647d8591b1569994b805b7e384881a7eff3ea87862594760fc"
+
+
+def draw_digest(cfg, seed):
+    import jax
+    am = arch_module(cfg)
+    arch = am.Arch(cfg)
+    w = am.make_weights(arch, seed)
+    h = hashlib.sha256()
+    for tree in (w, am.make_projector(arch, w)):
+        for k in sorted(tree):
+            a = np.asarray(jax.device_get(tree[k]))
+            h.update(k.encode())
+            h.update(str(a.dtype).encode())
+            h.update(a.tobytes())
+    return h.hexdigest(), w
+
+
+def test_untied_draw_is_what_it_was():
+    assert draw_digest(tiny_config(), 2 ** 31 + 7)[0] == UNTIED_DRAW_SHA256
+
+
+def test_tied_embedding_is_drawn_at_the_heads_scale():
+    untied, tied = tiny_config(), tiny_config("tiny-gqa-tied")
+    assert {k for k in set(untied) | set(tied) if untied.get(k) !=
+            tied.get(k)} == {"name", "source", "tie_word_embeddings"}
+    _, wu = draw_digest(untied, 5)
+    _, wt = draw_digest(tied, 5)
+    assert "head" in wu and "head" not in wt
+    std = lambda w: float(np.std(np.asarray(w["embed"], np.float32)))  # noqa: E731
+    d = untied["hidden_size"]
+    assert std(wu) == pytest.approx(1.0, rel=0.05)
+    assert std(wt) == pytest.approx(d ** -0.5, rel=0.05)
+
+
+# ---------------------------------------------------------------------------
 # whole runs at a tiny size
 # ---------------------------------------------------------------------------
 
@@ -283,15 +347,18 @@ def tiny_bench(tmp_path, monkeypatch):
             shutil.copy(os.path.join(DATA, sub, f), here / sub / f)
     monkeypatch.setattr(model, "HERE", str(here))
     b = load_bench()
-    # a metric of some cells goes to the tiny cell of the same traffic kind
-    kinds = {"resident": "tiny-gqa.resident", "open_loop": "tiny-gqa.chat"}
-    tiny = {w["name"]: kinds[load_traffic(w["traffic"])["kind"]]
-            for w in b["workloads"]}
+    kind = lambda w: load_traffic(w["traffic"])["kind"]  # noqa: E731
+    real = {w["name"]: kind(w) for w in b["workloads"]}
     b["workloads"] = [
         {"name": f"tiny-gqa.{k}", "config": "tiny-gqa", "traffic": f"tiny-{k}",
-         "chips": 1, "why": "test"} for k in ("resident", "chat")]
-    b["end_to_end"] = [dict(m, workloads=[tiny[w] for w in m["workloads"]])
-                       if "workloads" in m else m for m in b["end_to_end"]]
+         "chips": 1, "why": "test"} for k in ("resident", "chat")] + [
+        {"name": "tiny-gqa-tied.resident", "config": "tiny-gqa-tied",
+         "traffic": "tiny-resident", "chips": 1, "why": "test"}]
+    # a metric of some cells goes to the tiny cells of the same traffic kind
+    b["end_to_end"] = [
+        dict(m, workloads=[w["name"] for w in b["workloads"] if kind(w) in
+                           {real[n] for n in m["workloads"]}])
+        if "workloads" in m else m for m in b["end_to_end"]]
     b["per_layer"] = []
     return here, b
 
@@ -317,36 +384,94 @@ def test_new_config_traffic_and_metric_are_files_alone(tiny_bench):
     b["workloads"].append({"name": "made-up.made-up-chat",
                            "config": "made-up", "traffic": "made-up-chat",
                            "chips": 1, "why": "added as files"})
+    for m in b["end_to_end"]:         # the cell joins the chat cells' tail
+        if "tiny-gqa.chat" in m.get("workloads", []):
+            m["workloads"].append("made-up.made-up-chat")
     b["end_to_end"].append({"name": "made_up_tokens", "unit": "tokens",
                             "better": "higher", "bound": 0.1,
                             "source": "host_clock"})
     res = tiny_run(b, "made-up.made-up-chat", 3, seconds=1.0)
     assert res["metrics"]["made_up_tokens"]["value"] > 0
-    assert set(res["metrics"]) == {"itl_p95_ms", "setup_s", "made_up_tokens"}
+    assert set(res["metrics"]) == {"itl_p99_ms", "setup_s", "made_up_tokens"}
     assert res["correct"], res["checks"]
     assert res["attempted"] >= 3 and res["failed"] == 0
 
 
-def test_program_passes_and_fp8_control_fails(tiny_bench):
+def logged_copy(base, names, log):
+    """A module that stands in for ``base`` under another name and logs
+    each of ``names`` that is called."""
+    lines = ['"""Added as a file: %s under another name."""' % base,
+             "from bench.model import load_module",
+             "",
+             f"_base = load_module({base!r})",
+             "counts = getattr(_base, 'counts', None)",
+             "",
+             "",
+             "def _logged(name):",
+             "    def call(*a, **kw):",
+             f"        with open({str(log)!r}, 'a') as f:",
+             "            f.write(name + '\\n')",
+             "        return getattr(_base, name)(*a, **kw)",
+             "    return call",
+             ""]
+    return "\n".join(lines + [f"{n} = _logged({n!r})" for n in names]) + "\n"
+
+
+def test_new_architecture_and_reference_are_files_alone(tiny_bench,
+                                                        tmp_path):
+    here, b = tiny_bench
+    log = tmp_path / "calls.log"
+    arch_calls = ["Arch", "make_weights", "make_projector", "program_params",
+                  "program_configs"]
+    ref_calls = ["hidden", "head_stats"]
+    (here / "archs" / "made_up_gqa.py").write_text(
+        logged_copy("bench/archs/dense_gqa.py", arch_calls, log))
+    (here / "reference" / "made_up_gqa.py").write_text(
+        logged_copy("bench/reference/dense_gqa.py", ref_calls, log))
+    cfg = tiny_config()
+    cfg.update(name="made-up-arch", architecture="bench/archs/made_up_gqa.py",
+               reference="bench/reference/made_up_gqa.py")
+    (here / "configs" / "made-up-arch.json").write_text(json.dumps(cfg))
+    shutil.copy(here / "limits" / "tiny-gqa.resident.json",
+                here / "limits" / "made-up-arch.resident.json")
+    b["workloads"].append({"name": "made-up-arch.resident",
+                           "config": "made-up-arch",
+                           "traffic": "tiny-resident", "chips": 1,
+                           "why": "an architecture added as files"})
+    res = tiny_run(b, "made-up-arch.resident", 2 ** 31 + 99)
+    assert res["correct"], res["checks"]
+    assert set(log.read_text().split()) == set(arch_calls + ref_calls)
+    for f in ("harness.py", "model.py"):     # neither was edited
+        assert (here / f).read_text() == \
+            open(os.path.join(ROOT, "bench", f)).read()
+
+
+@pytest.mark.parametrize("cell", ["tiny-gqa.resident",
+                                  "tiny-gqa-tied.resident"])
+def test_program_passes_and_fp8_control_fails(tiny_bench, cell):
     _, b = tiny_bench
-    res = tiny_run(b, "tiny-gqa.resident", 2 ** 31 + 99, control=True)
+    res = tiny_run(b, cell, 2 ** 31 + 99, control=True)
     gap, ctl = res["checks"]["max_logit_gap"], res["checks"]["control_gap"]
     # the control stands in the program's place, so the run is not correct
     assert not res["correct"], res["checks"]
     assert gap["value"] <= gap["limit"] < ctl["value"], res["checks"]
-    assert tiny_run(b, "tiny-gqa.resident", 2 ** 31 + 99)["correct"]
+    assert tiny_run(b, cell, 2 ** 31 + 99)["correct"]
     assert list(res)[-2:] == ["checks", "_recording"]
     assert set(res["metrics"]) == {"output_tok_s", "itl_p95_ms", "setup_s"}
 
 
-@pytest.mark.parametrize("cell", ["resident", "chat"])
+FAULT_CELLS = {"resident": "tiny-gqa.resident", "chat": "tiny-gqa.chat",
+               "tied-resident": "tiny-gqa-tied.resident"}
+
+
+@pytest.mark.parametrize("cell", sorted(FAULT_CELLS))
 @pytest.mark.parametrize("fault", ["token_altered", "state_unchanged",
                                    "half_batch"])
 def test_faults_under_the_timed_path_are_not_correct(tiny_bench, fault,
                                                      cell):
     from bench.faults import FAULTS
     _, b = tiny_bench
-    res = tiny_run(b, f"tiny-gqa.{cell}", 11, seconds=1.0 if cell == "chat"
+    res = tiny_run(b, FAULT_CELLS[cell], 11, seconds=1.0 if cell == "chat"
                    else 0.5, fault=FAULTS[fault])
     assert not res["correct"], res["checks"]
     assert res["checks"]["max_logit_gap"]["value"] > \
@@ -383,11 +508,12 @@ def test_reference_sals_with_a_full_budget_is_exact_attention():
     selectable token, the SALS rows of the reference attend like exact
     attention, up to the int8 values; a small budget does not."""
     from bench.reference import dense_gqa
-    cfg = json.load(open(os.path.join(DATA, "configs", "tiny-gqa.json")))
+    cfg = tiny_config()
     cfg["sals"] = dict(cfg["sals"], rank_ratio=1.0)
-    arch = model.Arch(cfg)
-    w = model.make_weights(arch, 5)
-    u = model.make_projector(arch, w)["u"]
+    am = arch_module(cfg)
+    arch = am.Arch(cfg)
+    w = am.make_weights(arch, 5)
+    u = am.make_projector(arch, w)["u"]
     rng = np.random.default_rng(0)
     toks = rng.integers(1, arch.vocab, 200).astype(np.int32)
     rows = np.arange(100, 228).clip(max=199).astype(np.int32)

@@ -158,16 +158,18 @@ def test_attach_reads_spans_from_a_cpu_profile(tmp_path):
 # -- recordings -----------------------------------------------------------
 
 def recording_context(path):
-    from bench import counts, harness, model
+    from bench import harness, model
     from bench.traffic import load_traffic
     with open(path) as f:
         rec = json.load(f)
     steps = [tuple(s) for s in rec["steps"]]
+    cfg = model.load_config(rec["config"])
+    am = model.load_module(cfg["architecture"])
     return harness.Context(
         recs=[], extract=rec["extract"], steps=steps,
         tr0=min(s[0] for s in steps), tr1=max(s[0] for s in steps),
-        arch=model.Arch(model.load_config(rec["config"])),
-        traffic=load_traffic(rec["traffic"]), counts=counts, trace=trace,
+        arch=am.Arch(cfg), traffic=load_traffic(rec["traffic"]),
+        counts=am.counts, trace=trace,
         peaks=model.load_json("peaks.json"), device_kind=rec["device_kind"])
 
 
